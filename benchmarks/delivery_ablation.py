@@ -23,6 +23,7 @@ from repro.api import Simulator
 from repro.configs.microcircuit import MicrocircuitConfig
 from repro.core import delivery as dlv
 from repro.core import connectivity as conn
+from repro.launch.runtime import setup_jax
 
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts", "bench")
 
@@ -62,6 +63,7 @@ def gated_skip_fraction(spikes_per_step: float, n: int,
 
 
 def main():
+    setup_jax()
     os.makedirs(ART, exist_ok=True)
     rows = []
     for scale in SCALES:

@@ -11,6 +11,7 @@ from __future__ import annotations
 from benchmarks.common import fmt_row
 from repro.api import Simulator
 from repro.configs.microcircuit import MicrocircuitConfig
+from repro.launch.runtime import setup_jax
 
 
 def run(scale: float = 0.05, steps: int = 2000, strategy: str = "event"):
@@ -33,6 +34,7 @@ def run(scale: float = 0.05, steps: int = 2000, strategy: str = "event"):
 
 
 def main():
+    setup_jax()
     for strategy in ("event", "dense"):
         sc = 0.05 if strategy == "event" else 0.02
         for r in run(scale=sc, steps=500, strategy=strategy):

@@ -1,13 +1,15 @@
 """Roofline analysis: dry-run artifacts + the live step program.
 
-Two modes share the v5e constants:
+Both modes take their peaks from ``repro.perf.peaks``, keyed by device
+kind:
 
-**Dry-run cells** (default; EXPERIMENTS.md §Roofline) — three terms per
-(arch x shape x mesh) cell, in seconds per step:
+**Dry-run cells** (default) — three terms per (arch x shape x mesh) cell,
+in seconds per step, for the v5e pod ``launch/dryrun.py`` lays the cells
+out on:
 
-  compute    = HLO_FLOPs_per_device / 197e12          (bf16 peak, v5e)
-  memory     = HLO_bytes_per_device / 819e9            (HBM bandwidth)
-  collective = wire_bytes_per_device / 50e9            (ICI per-link)
+  compute    = HLO_FLOPs_per_device / bf16 peak
+  memory     = HLO_bytes_per_device / HBM bandwidth
+  collective = wire_bytes_per_device / ICI per-link bandwidth
 
 plus MODEL_FLOPS = 6·N_active·tokens (train) or 2·N_active·tokens
 (prefill/decode) and the usefulness ratio MODEL_FLOPS / total_HLO_FLOPs
@@ -18,11 +20,8 @@ plus MODEL_FLOPS = 6·N_active·tokens (train) or 2·N_active·tokens
 (``repro.analysis.hlo_contract.fused_step_hlo``), its per-step FLOPs and
 HBM bytes extracted (``repro.perf.hlo_analysis.analyze_hlo``), and —
 when a measured per-step wall time is folded in — converted to achieved
-FLOP/s and bytes/s against the v5e peaks.  On a CPU host the achieved
-percentages use the v5e denominators unchanged: they are projection
-ratios ("what fraction of a v5e roofline this step program would need"),
-not a claim about the CPU's own roofline — the honest number is the
-bytes/FLOPs-per-step pair, which is machine-independent.
+FLOP/s and bytes/s against the peaks of the device it ran on.  A device
+with no published peaks (the CPU among them) raises.
 """
 from __future__ import annotations
 
@@ -31,10 +30,11 @@ import json
 import os
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
-HBM_BYTES = 16 * 2 ** 30
+from repro.launch.runtime import setup_jax
+from repro.perf.peaks import peaks_for
+
+#: The chip ``launch/dryrun.py`` compiles its production mesh for.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
 
 ART_DIR = os.path.join(os.path.dirname(__file__), "..", "artifacts", "dryrun")
 
@@ -51,16 +51,17 @@ def model_flops(cell: dict) -> float:
 
 
 def analyze(cell: dict) -> dict:
-    comp = cell["flops_per_device"] / PEAK_FLOPS
+    peaks = peaks_for(DRYRUN_DEVICE_KIND)
+    comp = cell["flops_per_device"] / peaks.flops_bf16
     # memory traffic bounds: the HLO-derived count assumes every top-level
     # op round-trips HBM (true on the un-fused CPU backend; a *ceiling* for
     # TPU, which fuses elementwise chains); the floor is compulsory traffic:
     # every argument/output byte touched once.
-    mem_ceiling = cell["bytes_accessed_per_device"] / HBM_BW
+    mem_ceiling = cell["bytes_accessed_per_device"] / peaks.hbm_bw
     compulsory = (cell["memory"]["argument_bytes"]
                   + cell["memory"]["output_bytes"])
-    mem_floor = compulsory / HBM_BW
-    coll = cell["collective_wire_bytes_per_device"] / ICI_BW
+    mem_floor = compulsory / peaks.hbm_bw
+    coll = cell["collective_wire_bytes_per_device"] / peaks.ici_link_bw
     terms_opt = {"compute": comp, "memory": mem_floor, "collective": coll}
     terms_pes = {"compute": comp, "memory": mem_ceiling, "collective": coll}
     dominant = max(terms_pes, key=terms_pes.get)
@@ -85,10 +86,11 @@ def analyze(cell: dict) -> dict:
         "step_lower_bound_s": lo,
         "model_flops": mf,
         "useful_flops_ratio": (mf / total_hlo) if total_hlo else 0.0,
-        "mfu_bound": (mf / (cell["n_devices"] * PEAK_FLOPS * hi) if hi else 0,
-                      mf / (cell["n_devices"] * PEAK_FLOPS * lo) if lo else 0),
+        "mfu_bound": (
+            mf / (cell["n_devices"] * peaks.flops_bf16 * hi) if hi else 0,
+            mf / (cell["n_devices"] * peaks.flops_bf16 * lo) if lo else 0),
         "bytes_per_device": mem_bytes,
-        "fits_hbm": mem_bytes <= HBM_BYTES,
+        "fits_hbm": mem_bytes <= peaks.hbm_bytes,
     }
 
 
@@ -150,7 +152,8 @@ def live_roofline(sim, *, n_steps: int = 100) -> Dict:
 
     Lowers the backend's scan runner for ``n_steps`` (AOT — nothing runs
     on the device), divides the module totals by ``n_steps``, and places
-    the step on the v5e roofline.  FLOPs = dot + elementwise terms (a
+    the step on the roofline of the device it runs on (raises where
+    ``repro.perf.peaks`` lists no such device).  FLOPs = dot + elementwise terms (a
     spiking step is dot-free, so the elementwise term carries it).
 
     The byte count is a *ceiling*: every top-level op is charged a full
@@ -165,6 +168,8 @@ def live_roofline(sim, *, n_steps: int = 100) -> Dict:
 
     import jax
 
+    kind = jax.devices()[0].device_kind
+    peaks = peaks_for(kind)
     hlo = fused_step_hlo(sim, n_steps=n_steps)
     a = analyze_hlo(hlo)
     flops = (a["flops_per_device"]
@@ -177,25 +182,26 @@ def live_roofline(sim, *, n_steps: int = 100) -> Dict:
     floor_b = 2.0 * sum(x.size * x.dtype.itemsize
                         for x in jax.tree_util.tree_leaves(state)
                         if hasattr(x, "dtype"))
-    compute_s = flops / PEAK_FLOPS
-    mem_floor_s = floor_b / HBM_BW
-    mem_ceil_s = ceil_b / HBM_BW
+    compute_s = flops / peaks.flops_bf16
+    mem_floor_s = floor_b / peaks.hbm_bw
+    mem_ceil_s = ceil_b / peaks.hbm_bw
     dt_s = float(sim.sim_config.dt) * 1e-3
     pol = sim.sim_config.kernels
     return {
+        "device_kind": kind,
         "n_steps_analyzed": n_steps,
         "flops_per_step": flops,
         "hbm_bytes_per_step_floor": floor_b,
         "hbm_bytes_per_step_ceiling": ceil_b,
         "arithmetic_intensity_floor": (flops / floor_b) if floor_b else 0.0,
-        "compute_s_v5e": compute_s,
-        "memory_floor_s_v5e": mem_floor_s,
-        "memory_ceiling_s_v5e": mem_ceil_s,
+        "compute_s": compute_s,
+        "memory_floor_s": mem_floor_s,
+        "memory_ceiling_s": mem_ceil_s,
         "dominant": "memory" if mem_floor_s >= compute_s else "compute",
-        "step_bound_s_v5e": (max(compute_s, mem_floor_s),
-                             max(compute_s, mem_ceil_s)),
-        "rtf_bound_v5e": (max(compute_s, mem_floor_s) / dt_s,
-                          max(compute_s, mem_ceil_s) / dt_s),
+        "step_bound_s": (max(compute_s, mem_floor_s),
+                         max(compute_s, mem_ceil_s)),
+        "rtf_bound": (max(compute_s, mem_floor_s) / dt_s,
+                      max(compute_s, mem_ceil_s) / dt_s),
         "kernels": None if pol is None else pol.describe(),
     }
 
@@ -207,6 +213,7 @@ def with_achieved(roof: Dict, step_s: float) -> Dict:
     traffic the step cannot avoid — so the percentage stays meaningful on
     hosts where the ceiling model overstates (see ``live_roofline``).
     """
+    peaks = peaks_for(roof["device_kind"])
     return {
         **roof,
         "measured_step_s": step_s,
@@ -214,9 +221,9 @@ def with_achieved(roof: Dict, step_s: float) -> Dict:
         "achieved_hbm_bytes_per_s":
             roof["hbm_bytes_per_step_floor"] / step_s,
         "pct_peak_flops": 100.0 * roof["flops_per_step"] / step_s
-                          / PEAK_FLOPS,
+                          / peaks.flops_bf16,
         "pct_peak_hbm": 100.0 * roof["hbm_bytes_per_step_floor"] / step_s
-                        / HBM_BW,
+                        / peaks.hbm_bw,
     }
 
 
@@ -236,6 +243,7 @@ def live_report(scale: float = 0.05, kernels: str = "auto",
 
 
 def main(argv=None):
+    setup_jax()
     import argparse
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--live", action="store_true",
@@ -255,8 +263,8 @@ def main(argv=None):
               f"flops={r['flops_per_step']:.3g};"
               f"bytes_floor={r['hbm_bytes_per_step_floor']:.3g};"
               f"dom={r['dominant']};"
-              f"rtf_bound_v5e={r['rtf_bound_v5e'][0]:.2e}"
-              f"..{r['rtf_bound_v5e'][1]:.2e};"
+              f"rtf_bound={r['rtf_bound'][0]:.2e}"
+              f"..{r['rtf_bound'][1]:.2e};"
               f"pct_peak_hbm={r['pct_peak_hbm']:.3f}")
         print(json.dumps(r, indent=2))
         return

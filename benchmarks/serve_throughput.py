@@ -31,6 +31,7 @@ import numpy as np
 
 from benchmarks import common
 from benchmarks.common import fmt_row
+from repro.launch.runtime import setup_jax
 
 SCALE = 0.02
 RUN_MS = 20.0         # per-request horizon
@@ -141,6 +142,7 @@ def measure() -> list:
 
 
 def main(argv=None) -> int:
+    setup_jax()
     ap = argparse.ArgumentParser(
         description="serve throughput ledger benchmark")
     ap.add_argument("--out", default=None, metavar="PATH",

@@ -14,8 +14,11 @@ import json
 import os
 
 from benchmarks.common import fmt_row, time_sim
+from benchmarks.roofline import DRYRUN_DEVICE_KIND
 from repro.api import Simulator
 from repro.configs.microcircuit import MicrocircuitConfig
+from repro.launch.runtime import setup_jax
+from repro.perf.peaks import peaks_for
 
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts", "dryrun")
 
@@ -60,9 +63,11 @@ def projected_rows():
         with open(path) as f:
             cell = json.load(f)
         steps = 100.0                      # the dry-run lowers a 100-step chunk
-        comp = cell["flops_per_device"] / steps / 197e12
-        mem = _event_mem_bytes_per_step(chips) / 819e9
-        coll = cell["collective_wire_bytes_per_device"] / steps / 50e9
+        peaks = peaks_for(DRYRUN_DEVICE_KIND)
+        comp = cell["flops_per_device"] / steps / peaks.flops_bf16
+        mem = _event_mem_bytes_per_step(chips) / peaks.hbm_bw
+        coll = (cell["collective_wire_bytes_per_device"] / steps
+                / peaks.ici_link_bw)
         lat = STEP_LATENCY_S[chips]
         step_s = max(comp, mem, coll) + lat
         rtf = step_s / 1e-4                # 0.1 ms of model time per step
@@ -73,6 +78,7 @@ def projected_rows():
 
 
 def main():
+    setup_jax()
     for r in measured_rows() + projected_rows():
         print(r)
 

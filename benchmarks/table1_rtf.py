@@ -9,8 +9,9 @@ Ledger modes turn the measurement into a regression gate:
     # measure the strategy x scale sweep, persist the ledger
     python benchmarks/table1_rtf.py --sweep --out artifacts/bench/BENCH_rtf.json
 
-    # ... with per-step roofline numbers (achieved vs v5e peak) and the
-    # fused one-kernel-step rows attached to every entry
+    # ... with per-step roofline numbers (achieved vs the peaks of the
+    # device it ran on; raises off the chips repro.perf.peaks lists) and
+    # the fused one-kernel-step rows attached to every entry
     python benchmarks/table1_rtf.py --sweep --roofline --out BENCH_rtf.json
 
     # ... and flag regressions against the committed reference ledger
@@ -36,9 +37,12 @@ import numpy as np
 
 from benchmarks import common
 from benchmarks.common import fmt_row, time_sim
+from benchmarks.roofline import DRYRUN_DEVICE_KIND
 from repro.api import Simulator
 from repro.configs.microcircuit import MicrocircuitConfig
 from repro.core.params import FULL_MEAN_RATES, N_FULL, POPULATIONS
+from repro.launch.runtime import setup_jax
+from repro.perf.peaks import peaks_for
 
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts", "dryrun")
 
@@ -74,9 +78,11 @@ def projected(mesh: str, chips: int):
     with open(path) as f:
         cell = json.load(f)
     steps = 100.0
-    comp = cell["flops_per_device"] / steps / 197e12
-    mem = _event_mem_bytes_per_step(chips) / 819e9
-    coll = cell["collective_wire_bytes_per_device"] / steps / 50e9
+    peaks = peaks_for(DRYRUN_DEVICE_KIND)
+    comp = cell["flops_per_device"] / steps / peaks.flops_bf16
+    mem = _event_mem_bytes_per_step(chips) / peaks.hbm_bw
+    coll = (cell["collective_wire_bytes_per_device"] / steps
+            / peaks.ici_link_bw)
     lat = {256: 6e-6, 512: 8e-6}[chips]
     rtf = (max(comp, mem, coll) + lat) / 1e-4
     # energy per synaptic event at that RTF
@@ -92,7 +98,8 @@ def single_chip_projection():
                            / sum(N_FULL.values())) * 1e-4
     deliver_bytes = spikes * 3876 * 9
     state_bytes = 77169 * 6 * 4 * 2
-    step_s = (deliver_bytes + state_bytes) / 819e9 + 2e-6
+    step_s = ((deliver_bytes + state_bytes)
+              / peaks_for(DRYRUN_DEVICE_KIND).hbm_bw + 2e-6)
     rtf = step_s / 1e-4
     e = CHIP_POWER_W * rtf / full_scale_event_rate()
     return rtf, e * 1e6
@@ -213,6 +220,7 @@ def run_sweep(scales, strategies, t_sim_ms: float, seed: int = 3,
 
 
 def main(argv=None) -> int:
+    setup_jax()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--sweep", action="store_true",
                     help="measure the strategy x scale RTF sweep")

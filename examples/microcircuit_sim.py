@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.api import Experiment
 from repro.configs.microcircuit import MicrocircuitConfig
+from repro.launch.runtime import setup_jax
 
 
 def build_experiment(args) -> Experiment:
@@ -63,6 +64,7 @@ def build_experiment(args) -> Experiment:
 
 
 def main():
+    setup_jax()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scenario", default=None, metavar="PATH",
                     help="run a repro.experiment/v1 scenario JSON (CLI "

@@ -20,6 +20,8 @@ from __future__ import annotations
 
 import argparse
 
+from repro.launch.runtime import setup_jax
+
 SCENARIO = "examples/scenarios/smoke_background.json"
 
 
@@ -85,6 +87,7 @@ def over_http(scenario: str) -> None:
 
 
 def main() -> None:
+    setup_jax()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--scenario", default=SCENARIO)
     ap.add_argument("--http", action="store_true",

@@ -37,6 +37,7 @@ from typing import Any, ClassVar, Dict, FrozenSet, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 from repro.api.probes import Probe, ProbeContext, StreamProbe, split_probes
 from repro.core import delivery as dlv
@@ -428,11 +429,12 @@ class FusedBackend(Backend):
             dep_coef, _, decay_p, decay_m = PL.stdp_coefficients(bound.cfg)
 
             def runner(state, net, tables, carries):
-                k_ell = net.tables.targets.shape[1]
-                pmask = tables.plastic_out
-                if k_ell != k_out:            # ELL pad, no reorder
-                    pmask = jnp.pad(pmask,
-                                    ((0, 0), (0, k_ell - k_out)))
+                # the kernel reads the mask as int32 tiles shaped like the
+                # ELL tables (ELL pad, no reorder)
+                rows_ell, k_ell = net.tables.targets.shape
+                pmask = jnp.pad(tables.plastic_out.astype(jnp.int32),
+                                ((0, rows_ell - (n + 1)),
+                                 (0, k_ell - k_out)))
 
                 def step(carry, _):
                     (sim, ps, spk_prev), scs = carry
@@ -453,7 +455,7 @@ class FusedBackend(Backend):
                         decay_p=decay_p, decay_m=decay_m,
                         interpret=pol.interpret)
                     w_flat = jnp.concatenate(
-                        [w_out[:, :k_out].reshape(-1),
+                        [w_out[:n + 1, :k_out].reshape(-1),
                          ps.weights[(n + 1) * k_out:]])
                     w_flat = PL.stdp_pot_clip(w_flat, ps.x_pre, ids,
                                               tables, bound.cfg,
@@ -688,7 +690,12 @@ class ShardedBackend(Backend):
         self.n_dev = n_dev
         from repro.launch.mesh import make_mesh_auto
         self.mesh = make_mesh_auto((n_dev,), ("flat",))
-        self.tables, self.meta = strategy.localize(c, n_dev)
+        tables, self.meta = strategy.localize(c, n_dev)
+        # each device receives only its own columns, straight from the host
+        specs = DD.table_specs(self.mesh.axis_names)
+        self.tables = DD.ShardedTables(*(
+            jax.device_put(x, NamedSharding(self.mesh, p))
+            for x, p in zip(tables, specs)))
         self.n_pops = len(c.pop_sizes)
         spike_b, cur_b = self.drive.padded_bases(self.meta["n_pad"])
         self._drive_bases = (jnp.asarray(spike_b), jnp.asarray(cur_b))

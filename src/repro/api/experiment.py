@@ -39,6 +39,7 @@ from repro.api.results import BatchResult, RunResult
 from repro.configs.microcircuit import MicrocircuitConfig
 from repro.core import plasticity as plasticity_mod
 from repro.core import stimulus as stimulus_mod
+from repro.launch.runtime import setup_jax
 
 SCHEMA = "repro.experiment/v2"
 # v1 documents (pre-plasticity) load unchanged; a v1 document carrying a
@@ -284,6 +285,7 @@ class ExperimentResult:
 
 def main(argv=None) -> int:
     """Scenario runner CLI: load a JSON spec, run it, gate on validation."""
+    setup_jax()
     import argparse
 
     ap = argparse.ArgumentParser(

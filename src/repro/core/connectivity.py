@@ -145,32 +145,36 @@ def build_connectome(
                           else (d_mean[1], d_sd[1], d_hi[1]))
             d = _truncated_normal(rng, dm, ds, dt_bins, dh, k)
             db = np.maximum(1, np.round(d / dt_bins)).astype(np.int32)
-            srcs.append(s); tgts.append(t); ws.append(w); dbs.append(db)
+            # 4-byte types from here on: at full scale (~0.3e9 synapses)
+            # every 8-byte copy of a synapse array costs 2.4 GB of host RAM
+            srcs.append(s.astype(np.int32)); tgts.append(t.astype(np.int32))
+            ws.append(w.astype(np.float32)); dbs.append(db)
 
-    src = np.concatenate(srcs).astype(np.int64)
-    tgt = np.concatenate(tgts).astype(np.int32)
-    w = np.concatenate(ws).astype(np.float32)
-    db = np.concatenate(dbs).astype(np.int32)
+    src, tgt, w, db = (np.concatenate(x) for x in (srcs, tgts, ws, dbs))
+    del srcs, tgts, ws, dbs
     n_syn = src.shape[0]
 
     # --- ELL layout: group synapses by source -------------------------------
     order = np.argsort(src, kind="stable")
-    src, tgt, w, db = src[order], tgt[order], w[order], db[order]
     out_deg = np.bincount(src, minlength=n_total).astype(np.int32)
+    del src
     k_max = int(out_deg.max()) if n_syn else 1
     if k_pad_to is not None:
         if k_pad_to < k_max:
             raise ValueError(f"k_pad_to={k_pad_to} < max out-degree {k_max}")
         k_max = k_pad_to
-    row_start = np.concatenate([[0], np.cumsum(out_deg)]).astype(np.int64)
-    col = np.arange(n_syn, dtype=np.int64) - row_start[src]
-
+    # sorted by source, the synapses fill each row's first out_deg columns
+    # in row-major order: exactly the True entries of ``filled``
+    filled = np.arange(k_max, dtype=np.int32)[None, :] < out_deg[:, None]
     targets = np.full((n_total, k_max), n_total, dtype=np.int32)
     weights = np.zeros((n_total, k_max), dtype=np.float32)
     dbins = np.ones((n_total, k_max), dtype=np.int32)
-    targets[src, col] = tgt
-    weights[src, col] = w
-    dbins[src, col] = db
+    targets[filled] = tgt[order]
+    del tgt
+    weights[filled] = w[order]
+    del w
+    dbins[filled] = db[order]
+    del db, order, filled
 
     # --- external drive + down-scaling DC compensation ----------------------
     pop_of = np.repeat(np.arange(8, dtype=np.int32), n_pop)

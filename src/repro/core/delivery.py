@@ -394,32 +394,33 @@ class EllDelivery(DeliveryStrategy):
 
     name = "ell"
     block_k = 128            # ELL row tile width (lane-aligned)
-    #: The kernel holds the whole [2D, N+1] ring update as one VMEM-resident
-    #: output block; past this budget (full scale needs ~28 MB vs ~16 MB
-    #: VMEM) the automatic TPU path falls back to the XLA gather/scatter
-    #: until the column-tiled kernel variant lands.  An explicit
-    #: ``KernelPolicy(deliver='pallas')`` still forces the kernel.
+    row_tile = 8             # the kernels DMA aligned 8-row (sublane) tiles
+    #: The kernel holds the whole [2D, N+1] ring update in VMEM; past this
+    #: budget (full scale needs ~28 MB) the automatic TPU path keeps the
+    #: XLA gather/scatter.  An explicit ``KernelPolicy(deliver='pallas')``
+    #: still forces the kernel.
     kernel_max_ring_bytes = kpol.FUSED_MAX_RING_BYTES
 
     def prepare(self, c, cfg) -> EventTables:
-        targets = np.asarray(c.targets)
-        weights = np.asarray(c.weights)
-        dbins = np.asarray(c.dbins)
-        n, k = targets.shape
+        """ELL tables padded to whole ``(row_tile, block_k)`` tiles: extra
+        columns, the sentinel row N and any rows after it point at the dump
+        slot with weight 0.  Padded on the host, so each table crosses to
+        the device once (at full scale they are ~2 GB each)."""
+        n, k = c.targets.shape
         k_pad = max(self.block_k,
                     -(-k // self.block_k) * self.block_k)
-        if k_pad != k:
-            pad = ((0, 0), (0, k_pad - k))
-            targets = np.pad(targets, pad, constant_values=n)
-            weights = np.pad(weights, pad)
-            dbins = np.pad(dbins, pad, constant_values=1)
-        return make_event_tables(
-            jnp.asarray(targets), jnp.asarray(weights), jnp.asarray(dbins))
+        rows = -(-(n + 1) // self.row_tile) * self.row_tile
+        pad = ((0, rows - n), (0, k_pad - k))
+        return EventTables(
+            targets=jnp.asarray(np.pad(c.targets, pad, constant_values=n)),
+            weights=jnp.asarray(np.pad(c.weights, pad)),
+            dbins=jnp.asarray(np.pad(c.dbins, pad, constant_values=1)))
 
     def memory_bytes(self, c) -> int:
         n, k = c.targets.shape
         k_pad = max(self.block_k, -(-k // self.block_k) * self.block_k)
-        return (n + 1) * k_pad * (4 + 4 + 4)
+        rows = -(-(n + 1) // self.row_tile) * self.row_tile
+        return rows * k_pad * (4 + 4 + 4)
 
     def localize(self, c, n_dev, k_loc=None):
         # The sharded engine consumes the same ELL layout (its deliver is
@@ -436,11 +437,13 @@ class EllDelivery(DeliveryStrategy):
     def live_tables(self, tables: EventTables,
                     weights: jnp.ndarray) -> EventTables:
         """Pad the canonical [N+1, K] live weights to this strategy's
-        lane-aligned K (padded columns already point at the dump slot)."""
-        k_pad = tables.targets.shape[1]
-        k = weights.shape[1]
-        if k_pad != k:
-            weights = jnp.pad(weights, ((0, 0), (0, k_pad - k)))
+        tile-padded table (padded entries already point at the dump
+        slot)."""
+        rows_pad, k_pad = tables.targets.shape
+        rows, k = weights.shape
+        if (rows_pad, k_pad) != (rows, k):
+            weights = jnp.pad(weights, ((0, rows_pad - rows),
+                                        (0, k_pad - k)))
         return tables._replace(weights=weights)
 
     def deliver(self, ring, tables, spiked, t, n_exc, cfg):

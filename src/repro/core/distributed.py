@@ -47,40 +47,36 @@ def localize_ell(c: Connectome, n_dev: int,
     the sharded backend reaches it through
     ``repro.core.delivery.DeliveryStrategy.localize`` (``event`` and
     ``ell`` register it; strategies without a distributed layout raise).
+    The tables come back as host arrays, so the caller can place each
+    device's columns on that device (:func:`table_specs`) without first
+    holding the whole table on one of them.
     """
     n = c.n_total
     n_pad = -(-n // n_dev) * n_dev
     n_loc = n_pad // n_dev
 
-    src = np.repeat(np.arange(n), c.targets.shape[1])
-    tgt = c.targets.reshape(-1)
-    w = c.weights.reshape(-1)
-    db = c.dbins.reshape(-1)
-    valid = tgt < n
-    src, tgt, w, db = src[valid], tgt[valid], w[valid], db[valid]
-    dev = tgt // n_loc
-    tgt_local = tgt - dev * n_loc
-
-    # per (source, device) ragged rows -> padded k_loc
-    order = np.lexsort((tgt_local, dev, src))
-    src, dev, tgt_local = src[order], dev[order], tgt_local[order]
-    w, db = w[order], db[order]
-    cell = src.astype(np.int64) * n_dev + dev
-    counts = np.bincount(cell, minlength=n * n_dev)
-    k_max = int(counts.max()) if counts.size else 1
+    # owning device of every ELL entry; padding entries get none (n_dev)
+    dev = np.where(c.targets < n, c.targets // n_loc, n_dev)
+    k_max = max(int(np.count_nonzero(dev == d, axis=1).max())
+                for d in range(n_dev)) if n else 1
     if k_loc is None:
-        k_loc = k_max
+        k_loc = max(k_max, 1)
     elif k_loc < k_max:
         raise ValueError(f"k_loc={k_loc} < max {k_max}")
-    starts = np.concatenate([[0], np.cumsum(counts)])
-    col = np.arange(src.shape[0], dtype=np.int64) - starts[cell]
 
     T = np.full((n_pad + 1, n_dev, k_loc), n_loc, dtype=np.int32)
     W = np.zeros((n_pad + 1, n_dev, k_loc), dtype=np.float32)
     D = np.ones((n_pad + 1, n_dev, k_loc), dtype=np.int32)
-    T[src, dev, col] = tgt_local
-    W[src, dev, col] = w
-    D[src, dev, col] = db
+    for d in range(n_dev):
+        # a source's synapses onto device d keep their ELL (k) order, so
+        # every ring entry sums its arrivals in the single-device order
+        mine = dev == d
+        col = np.cumsum(mine, axis=1, dtype=np.int32) - 1
+        src, k = np.nonzero(mine)
+        T[src, d, col[src, k]] = c.targets[src, k] - d * n_loc
+        W[src, d, col[src, k]] = c.weights[src, k]
+        D[src, d, col[src, k]] = c.dbins[src, k]
+        del mine, col, src, k
 
     k_ext = np.zeros(n_pad, np.float32)
     k_ext[:n] = c.k_ext
@@ -88,14 +84,23 @@ def localize_ell(c: Connectome, n_dev: int,
     i_dc[:n] = c.i_dc
 
     tables = ShardedTables(
-        targets=jnp.asarray(T.reshape(n_pad + 1, n_dev * k_loc)),
-        weights=jnp.asarray(W.reshape(n_pad + 1, n_dev * k_loc)),
-        dbins=jnp.asarray(D.reshape(n_pad + 1, n_dev * k_loc)),
-        k_ext=jnp.asarray(k_ext),
-        i_dc=jnp.asarray(i_dc),
+        targets=T.reshape(n_pad + 1, n_dev * k_loc),
+        weights=W.reshape(n_pad + 1, n_dev * k_loc),
+        dbins=D.reshape(n_pad + 1, n_dev * k_loc),
+        k_ext=k_ext,
+        i_dc=i_dc,
     )
     meta = {"n_pad": n_pad, "n_loc": n_loc, "k_loc": k_loc, "n_dev": n_dev}
     return tables, meta
+
+
+def table_specs(axes) -> ShardedTables:
+    """How the sharded step splits its tables over the mesh ``axes``:
+    the ELL columns and the per-neuron drive by target-owning device."""
+    from jax.sharding import PartitionSpec as P
+    return ShardedTables(
+        targets=P(None, axes), weights=P(None, axes), dbins=P(None, axes),
+        k_ext=P(axes), i_dc=P(axes))
 
 
 def abstract_sharded_tables(c_meta: dict, n_dev: int, k_loc: int,
@@ -169,7 +174,7 @@ def make_sharded_step(mesh, meta: dict, prop: Propagators, *,
     replicated across devices, so the carries ride as replicated in/outputs
     — NEST-style streaming statistics without any extra collective.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if (bg_rate is None) == (drive is None):
@@ -187,9 +192,7 @@ def make_sharded_step(mesh, meta: dict, prop: Propagators, *,
     state_spec = ShardedSimState(
         V=P(axes), I_ex=P(axes), I_in=P(axes), refrac=P(axes),
         ring=P(None, None, axes), t=P(), key=P(axes), overflow=P(axes))
-    tab_spec = ShardedTables(
-        targets=P(None, axes), weights=P(None, axes), dbins=P(None, axes),
-        k_ext=P(axes), i_dc=P(axes))
+    tab_spec = table_specs(axes)
     stream_probes = tuple(stream_probes)
     carries_spec = jax.tree.map(
         lambda _: P(), tuple(p.init() for p in stream_probes))
@@ -281,7 +284,7 @@ def make_sharded_step(mesh, meta: dict, prop: Propagators, *,
             shard_map, mesh=mesh,
             in_specs=(state_spec, tab_spec, carries_spec, bases_spec),
             out_specs=(state_spec, counts_spec, carries_spec),
-            check_rep=False)
+            check_vma=False)
         def sim_chunk(state, tables, carries, bases):
             (state, carries), counts = jax.lax.scan(
                 functools.partial(step, tab=tables, bases=bases),
@@ -294,7 +297,7 @@ def make_sharded_step(mesh, meta: dict, prop: Propagators, *,
         shard_map, mesh=mesh,
         in_specs=(state_spec, tab_spec, carries_spec),
         out_specs=(state_spec, counts_spec, carries_spec),
-        check_rep=False)
+        check_vma=False)
     def sim_chunk(state, tables, carries):
         (state, carries), counts = jax.lax.scan(
             functools.partial(step, tab=tables), (state, carries), None,

@@ -92,9 +92,9 @@ def as_policy(kernels: Union[None, str, KernelPolicy]) -> KernelPolicy:
 
 
 def _ring_bytes(n_total: int, d_max_bins: int) -> int:
-    """Bytes of the lane-padded f32 ring the kernels keep in VMEM."""
-    n_cols_pad = -(-(n_total + 1) // 128) * 128
-    return 2 * d_max_bins * n_cols_pad * 4
+    """Bytes of the tile-padded f32 ring the kernels keep in VMEM."""
+    from repro.kernels.ell_deliver import ring_lanes
+    return 2 * d_max_bins * ring_lanes(n_total + 1) * 4
 
 
 def fused_eligible(strategy: str, state_dtype, n_total: int,

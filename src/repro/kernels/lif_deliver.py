@@ -6,16 +6,18 @@ a ring slot plus five state arrays, integrates, and writes them back.
 This kernel keeps the whole delay ring, the membrane state, and the
 scalar-prefetched spike ids resident on-chip across one step:
 
+* the ring is DMA'd from HBM into a VMEM scratch buffer at the first grid
+  step and back (aliased in place) after the last, in the tiled layout of
+  :mod:`repro.kernels.ell_deliver`;
 * grid ``(S+1, K/block_k)`` — the first ``S`` rows replay the sparse-ELL
-  delivery of the *previous* step's spike ids (gathered row tiles, scalar
-  scatter into the VMEM-resident ring, s-major / k-minor order, exactly
-  :mod:`repro.kernels.ell_deliver`), scattering directly onto the aliased
-  ring block;
+  delivery of the *previous* step's spike ids (row tiles DMA'd from HBM
+  into SMEM, masked-tile scatter into the resident ring, s-major / k-minor
+  order, exactly :mod:`repro.kernels.ell_deliver`);
 * the final grid row (``s == S, kb == 0``) runs the whole-network LIF
   update of :mod:`repro.kernels.lif_update` against the just-scattered
-  ring: it reads the current slot's arrival rows, integrates with the
-  propagator immediates, detects spikes, and zeroes the consumed slot —
-  all before the ring block is flushed to HBM once.
+  ring, tile by tile: it reads the current slot's arrival rows, integrates
+  with the propagator immediates, detects spikes, and zeroes the consumed
+  slot — all before the ring is written back to HBM once.
 
 Because the kernel can only prefetch spike ids that exist *before* it
 runs, the fused loop is rotated one step: iteration ``i`` delivers
@@ -26,7 +28,7 @@ backends flush the final step's spikes with a split-path delivery
 epilogue after the scan.
 
 ``lif_deliver_plastic`` additionally folds the pair-STDP depression and
-trace decay into the same pass: each gathered ELL weight tile is written
+trace decay into the same pass: each fetched ELL weight tile is written
 back depressed (``w -= lr*A_minus*w_ref*x_post[target]`` on plastic
 synapses) while it is on-chip for the ring scatter, and the pre/post
 traces decay+bump in the LIF phase.  The potentiation scatter (indexed by
@@ -48,6 +50,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.neuron import Propagators
+from repro.kernels.ell_deliver import (LANE, SUB, TILE, deliver_row_tile,
+                                       dma, fetch_row_tile, gather_lane,
+                                       pad_table, ring_from_tiles,
+                                       ring_lanes, ring_to_tiles,
+                                       scatter_add, store_row_tile, to_tiles,
+                                       vmem_limit)
 
 
 def _lif_math(V, I_ex, I_in, refrac, in_ex, in_in, i_dc,
@@ -70,128 +78,161 @@ def _lif_math(V, I_ex, I_in, refrac, in_ex, in_in, i_dc,
     return Vo, iexo, iino, refo, spiked
 
 
-def _deliver_row(s, ids_ref, meta_ref, tgt_ref, w_ref, db_ref, ring_ref,
-                 *, d_bins: int, block_k: int):
-    """Scatter one gathered ELL tile into the resident ring block.
+def _lif_phase(meta_ref, ins, ring, outs, ring_out, sem, *, d_bins: int,
+               n_tiles: int, prop: Propagators):
+    """Integrate against the just-delivered ring, tile by tile, consume the
+    slot, then write the ring back to HBM."""
+    V_ref, iex_ref, iin_ref, ref_ref, ext_ref, idc_ref = ins
+    Vo_ref, iexo_ref, iino_ref, refo_ref, spk_ref = outs
+    slot = jax.lax.rem(meta_ref[0] + 1, d_bins)
+    zeros = jnp.zeros((SUB, LANE), jnp.float32)
 
-    ``s`` is the grid row, computed at kernel top level: calling
-    ``pl.program_id`` inside a ``pl.when`` body breaks interpret mode
-    (the primitive lands in the cond sub-jaxpr, outside the grid env).
-    """
-    t_prev = meta_ref[0]
-    n_exc = meta_ref[1]
-    sid = ids_ref[s]
-    ch = jnp.where(sid >= n_exc, 1, 0).astype(jnp.int32)
-
-    def body(j, _):
-        tg = tgt_ref[0, j]
-        w = w_ref[0, j]
-        db = db_ref[0, j]
-        slot = jax.lax.rem(t_prev + db, d_bins)
-        ring_ref[slot * 2 + ch, tg] += w
+    def body(b, _):
+        res = _lif_math(V_ref[b], iex_ref[b], iin_ref[b], ref_ref[b],
+                        ring[slot * 2, b] + ext_ref[b], ring[slot * 2 + 1, b],
+                        idc_ref[b], prop)
+        for o, x in zip(outs, res):
+            o[b] = x.astype(o.dtype)
+        ring[slot * 2, b] = zeros
+        ring[slot * 2 + 1, b] = zeros
         return 0
 
-    jax.lax.fori_loop(0, block_k, body, 0)
+    jax.lax.fori_loop(0, n_tiles, body, 0)
+    dma(ring, ring_out, sem)
 
 
-def _lif_phase(meta_ref, V_ref, iex_ref, iin_ref, ref_ref, ext_ref,
-               idc_ref, ring_ref, Vo_ref, iexo_ref, iino_ref, refo_ref,
-               spk_ref, *, d_bins: int, n_lanes: int, prop: Propagators):
-    """Integrate against the just-delivered ring, then consume the slot."""
-    t_prev = meta_ref[0]
-    slot = jax.lax.rem(t_prev + 1, d_bins)
-    lanes = pl.dslice(0, n_lanes)
-    arr_ex = pl.load(ring_ref, (slot * 2, lanes))
-    arr_in = pl.load(ring_ref, (slot * 2 + 1, lanes))
-    in_ex = arr_ex + ext_ref[...]
-    Vo, iexo, iino, refo, spiked = _lif_math(
-        V_ref[...], iex_ref[...], iin_ref[...], ref_ref[...],
-        in_ex, arr_in, idc_ref[...], prop)
-    Vo_ref[...] = Vo
-    iexo_ref[...] = iexo
-    iino_ref[...] = iino
-    refo_ref[...] = refo
-    spk_ref[...] = spiked
-    zeros = jnp.zeros((n_lanes,), jnp.float32)
-    pl.store(ring_ref, (slot * 2, lanes), zeros)
-    pl.store(ring_ref, (slot * 2 + 1, lanes), zeros)
-
-
-def _kernel_static(ids_ref, meta_ref, tgt_ref, w_ref, db_ref, ring_in_ref,
+def _kernel_static(ids_ref, meta_ref, tgt_hbm, w_hbm, db_hbm, ring_in,
                    V_ref, iex_ref, iin_ref, ref_ref, ext_ref, idc_ref,
-                   ring_ref, Vo_ref, iexo_ref, iino_ref, refo_ref, spk_ref,
+                   ring_out, Vo_ref, iexo_ref, iino_ref, refo_ref, spk_ref,
+                   tgt_s, w_s, db_s, ring, sems,
                    *, d_bins: int, block_k: int, s_budget: int,
-                   n_lanes: int, prop: Propagators):
+                   n_tiles: int, prop: Propagators):
     s = pl.program_id(0)
     kb = pl.program_id(1)
 
     @pl.when((s == 0) & (kb == 0))
     def _init():
-        ring_ref[...] = ring_in_ref[...]
+        dma(ring_in, ring, sems.at[0])
 
     @pl.when(s < s_budget)
     def _deliver():
-        _deliver_row(s, ids_ref, meta_ref, tgt_ref, w_ref, db_ref,
-                     ring_ref, d_bins=d_bins, block_k=block_k)
+        deliver_row_tile(s, kb, ids_ref, meta_ref, (tgt_hbm, w_hbm, db_hbm),
+                         (tgt_s, w_s, db_s), ring, sems, d_bins=d_bins,
+                         block_k=block_k)
 
     @pl.when((s == s_budget) & (kb == 0))
     def _update():
-        _lif_phase(meta_ref, V_ref, iex_ref, iin_ref, ref_ref, ext_ref,
-                   idc_ref, ring_ref, Vo_ref, iexo_ref, iino_ref,
-                   refo_ref, spk_ref, d_bins=d_bins, n_lanes=n_lanes,
+        _lif_phase(meta_ref,
+                   (V_ref, iex_ref, iin_ref, ref_ref, ext_ref, idc_ref),
+                   ring, (Vo_ref, iexo_ref, iino_ref, refo_ref, spk_ref),
+                   ring_out, sems.at[0], d_bins=d_bins, n_tiles=n_tiles,
                    prop=prop)
 
 
-def _kernel_plastic(ids_ref, meta_ref, tgt_ref, w_ref, db_ref, pmask_ref,
-                    ring_in_ref, V_ref, iex_ref, iin_ref, ref_ref,
-                    ext_ref, idc_ref, xpre_ref, xpost_ref, spkprev_ref,
-                    ring_ref, w_out_ref, Vo_ref, iexo_ref, iino_ref,
-                    refo_ref, spk_ref, xpreo_ref, xposto_ref,
+def _kernel_plastic(ids_ref, meta_ref, tgt_hbm, w_hbm, db_hbm, pm_hbm,
+                    ring_in, V_ref, iex_ref, iin_ref, ref_ref, ext_ref,
+                    idc_ref, xpre_ref, xpost_ref, spkprev_ref,
+                    ring_out, w_out, Vo_ref, iexo_ref, iino_ref, refo_ref,
+                    spk_ref, xpreo_ref, xposto_ref,
+                    tgt_s, w_s, db_s, pm_s, ring, sems,
                     *, d_bins: int, block_k: int, s_budget: int,
-                    n_lanes: int, prop: Propagators, dep_coef: float,
+                    n_tiles: int, prop: Propagators, dep_coef: float,
                     decay_p: float, decay_m: float):
     s = pl.program_id(0)
     kb = pl.program_id(1)
 
     @pl.when((s == 0) & (kb == 0))
     def _init():
-        ring_ref[...] = ring_in_ref[...]
+        dma(ring_in, ring, sems.at[0])
 
     @pl.when(s < s_budget)
     def _deliver():
         t_prev = meta_ref[0]
-        n_exc = meta_ref[1]
         sid = ids_ref[s]
-        ch = jnp.where(sid >= n_exc, 1, 0).astype(jnp.int32)
+        ch = jnp.where(sid >= meta_ref[1], 1, 0).astype(jnp.int32)
+        # weights come from the aliased output: an earlier spike in the
+        # same 8-row tile has already written its depressed row there
+        r = fetch_row_tile(sid, kb, (tgt_hbm, w_out, db_hbm, pm_hbm),
+                           (tgt_s, w_s, db_s, pm_s), sems, block_k=block_k)
 
         def body(j, _):
-            tg = tgt_ref[0, j]
-            w = w_ref[0, j]
-            db = db_ref[0, j]
-            slot = jax.lax.rem(t_prev + db, d_bins)
-            ring_ref[slot * 2 + ch, tg] += w
-            # pair-STDP depression on the gathered tile while it's
+            tg = tgt_s[r, j]
+            w = w_s[r, j]
+            slot = jax.lax.rem(t_prev + db_s[r, j], d_bins)
+            scatter_add(ring, slot * 2 + ch, tg, w)
+            # pair-STDP depression on the fetched tile while it's
             # on-chip: same single-rounded coefficient as stdp_step
-            xp = xpost_ref[tg]
-            dw = jnp.where(pmask_ref[0, j], -(dep_coef * xp), 0.0)
-            w_out_ref[0, j] = w + dw
+            xp = gather_lane(xpost_ref, tg)
+            dw = jnp.where(pm_s[r, j] != 0, -(dep_coef * xp), 0.0)
+            w_s[r, j] = w + dw
             return 0
 
         jax.lax.fori_loop(0, block_k, body, 0)
+        store_row_tile(sid, kb, w_s, w_out, sems.at[0], block_k=block_k)
 
     @pl.when((s == s_budget) & (kb == 0))
     def _update():
-        _lif_phase(meta_ref, V_ref, iex_ref, iin_ref, ref_ref, ext_ref,
-                   idc_ref, ring_ref, Vo_ref, iexo_ref, iino_ref,
-                   refo_ref, spk_ref, d_bins=d_bins, n_lanes=n_lanes,
+        _lif_phase(meta_ref,
+                   (V_ref, iex_ref, iin_ref, ref_ref, ext_ref, idc_ref),
+                   ring, (Vo_ref, iexo_ref, iino_ref, refo_ref, spk_ref),
+                   ring_out, sems.at[0], d_bins=d_bins, n_tiles=n_tiles,
                    prop=prop)
         spkf = spkprev_ref[...]
         xpreo_ref[...] = xpre_ref[...] * decay_p + spkf
         xposto_ref[...] = xpost_ref[...] * decay_m + spkf
 
 
-def _pad_lanes(x, n_lanes):
-    return jnp.pad(x, (0, n_lanes - x.shape[0]))
+def _call(kernel, ids, t_prev, n_exc, tables, ring, vecs, n_vec_out,
+          extra_out, aliases, *, d_bins, n_cols, block_k, interpret):
+    """Shared ``pallas_call`` plumbing of the two fused kernels: HBM
+    tables and ring, tiled per-neuron vectors, one VMEM ring scratch.
+    ``vecs`` are the per-neuron input vectors; the last outputs are
+    ``n_vec_out`` tiled vectors, f32 but for the fourth (refractory
+    counter) and fifth (spikes, 0/1), which are int32."""
+    s_budget = ids.shape[0]
+    assert s_budget >= 1, "fused step needs spike_budget >= 1"
+    n_lanes = ring_lanes(n_cols)
+    n_tiles = n_lanes // TILE
+    ring_bytes = 2 * d_bins * n_lanes * 4
+    meta = jnp.stack([jnp.asarray(t_prev, jnp.int32),
+                      jnp.full((), n_exc, jnp.int32)])
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    vec = pl.BlockSpec((n_tiles, SUB, LANE),
+                       lambda s, kb, ids, meta: (0, 0, 0))
+    tile = jax.ShapeDtypeStruct((n_tiles, SUB, LANE), jnp.float32)
+    vec_out = [tile] * n_vec_out
+    vec_out[3] = jax.ShapeDtypeStruct(tile.shape, jnp.int32)      # refrac
+    vec_out[4] = jax.ShapeDtypeStruct(tile.shape, jnp.int32)      # spiked
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(s_budget + 1, tables[0].shape[1] // block_k),
+        in_specs=[hbm] * (len(tables) + 1) + [vec] * len(vecs),
+        out_specs=[hbm] * (1 + len(extra_out)) + [vec] * n_vec_out,
+        scratch_shapes=[pltpu.SMEM((SUB, block_k), tb.dtype)
+                        for tb in tables] + [
+            pltpu.VMEM((2 * d_bins, n_tiles, SUB, LANE), jnp.float32),
+            pltpu.SemaphoreType.DMA((len(tables),)),
+        ],
+    )
+    outs = pl.pallas_call(
+        functools.partial(kernel, d_bins=d_bins, block_k=block_k,
+                          s_budget=s_budget, n_tiles=n_tiles),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(
+            (2 * d_bins, n_tiles, SUB, LANE), jnp.float32)] + extra_out
+        + vec_out,
+        input_output_aliases=aliases,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(
+            ring_bytes, len(vecs) + n_vec_out, n_lanes)),
+        interpret=interpret,
+    )(ids, meta, *tables, ring_to_tiles(ring, n_lanes),
+      *[to_tiles(x, n_lanes) for x in vecs])
+    ring_out = ring_from_tiles(outs[0], n_cols)
+    return ring_out, outs[1:]
+
+
+def _vec(x, n):
+    return x.reshape(-1)[:n]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -203,63 +244,23 @@ def lif_deliver_pallas(ids, targets, weights, dbins, ring, V, I_ex, I_in,
     """One fused step: deliver ``ids`` at ring phase ``t_prev``, then
     integrate step ``t_prev + 1``.
 
-    ``ids``[S] int32 in [0, N] (N = sentinel), ELL tables ``[N+1, K]``,
+    ``ids``[S] int32 in [0, N] (N = sentinel), ELL tables ``[N+1, K]``
+    (rows past N, if any, are sentinel rows),
     ``ring``[D, 2, n_cols] f32, state vectors [n] (n = n_cols - 1),
     ``ext_ex``/``i_dc`` the pre-scaled external drive.  Returns
     ``(ring', V', I_ex', I_in', refrac', spiked)``.
     """
-    s_budget = ids.shape[0]
-    assert s_budget >= 1, "fused step needs spike_budget >= 1"
-    k = targets.shape[1]
-    k_pad = -(-k // block_k) * block_k
-    if k_pad != k:              # EllDelivery.prepare pre-pads; stay robust
-        n_sent = targets.shape[0] - 1
-        targets = jnp.pad(targets, ((0, 0), (0, k_pad - k)),
-                          constant_values=n_sent)
-        weights = jnp.pad(weights, ((0, 0), (0, k_pad - k)))
-        dbins = jnp.pad(dbins, ((0, 0), (0, k_pad - k)),
-                        constant_values=1)
-    n_lanes = -(-n_cols // 128) * 128
-    ring2 = jnp.pad(ring.reshape(2 * d_bins, n_cols),
-                    ((0, 0), (0, n_lanes - n_cols)))
-    meta = jnp.stack([jnp.asarray(t_prev, jnp.int32),
-                      jnp.full((), n_exc, jnp.int32)])
-    fvec = [_pad_lanes(x, n_lanes) for x in (V, I_ex, I_in)]
-    ivec = _pad_lanes(refrac, n_lanes)
-    dvec = [_pad_lanes(x, n_lanes) for x in (ext_ex, i_dc)]
-
-    last = s_budget - 1
-    row = pl.BlockSpec((1, block_k),
-                       lambda s, kb, ids, meta: (ids[jnp.minimum(s, last)],
-                                                 kb))
-    vec = pl.BlockSpec((n_lanes,), lambda s, kb, ids, meta: (0,))
-    full = pl.BlockSpec((2 * d_bins, n_lanes),
-                        lambda s, kb, ids, meta: (0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(s_budget + 1, k_pad // block_k),
-        in_specs=[row, row, row, full, vec, vec, vec, vec, vec, vec],
-        out_specs=[full, vec, vec, vec, vec, vec],
-    )
-    outs = pl.pallas_call(
-        functools.partial(_kernel_static, d_bins=d_bins, block_k=block_k,
-                          s_budget=s_budget, n_lanes=n_lanes, prop=prop),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((2 * d_bins, n_lanes), jnp.float32),
-            jax.ShapeDtypeStruct((n_lanes,), jnp.float32),
-            jax.ShapeDtypeStruct((n_lanes,), jnp.float32),
-            jax.ShapeDtypeStruct((n_lanes,), jnp.float32),
-            jax.ShapeDtypeStruct((n_lanes,), jnp.int32),
-            jax.ShapeDtypeStruct((n_lanes,), jnp.bool_),
-        ],
-        # input index 5 is the ring (indices count the 2 prefetch operands)
-        input_output_aliases={5: 0},
-        interpret=interpret,
-    )(ids, meta, targets, weights, dbins, ring2, *fvec, ivec, *dvec)
-    ring_out, Vo, iexo, iino, refo, spk = outs
-    ring_out = ring_out.reshape(d_bins, 2, n_lanes)[:, :, :n_cols]
-    return (ring_out, Vo[:n], iexo[:n], iino[:n], refo[:n], spk[:n])
+    tables = (pad_table(targets, block_k, n_cols - 1),
+              pad_table(weights, block_k, 0.0),
+              pad_table(dbins, block_k, 1))
+    ring_out, (Vo, iexo, iino, refo, spk) = _call(
+        functools.partial(_kernel_static, prop=prop), ids, t_prev, n_exc,
+        tables, ring, [V, I_ex, I_in, refrac, ext_ex, i_dc], 5, [],
+        # input 5 is the ring (indices count the 2 prefetch operands)
+        {5: 0}, d_bins=d_bins, n_cols=n_cols, block_k=block_k,
+        interpret=interpret)
+    return (ring_out, _vec(Vo, n), _vec(iexo, n), _vec(iino, n),
+            _vec(refo, n), _vec(spk, n) != 0)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -277,66 +278,30 @@ def lif_deliver_plastic_pallas(ids, targets, weights, dbins, pmask, ring,
     on-chip trace decay.
 
     ``weights`` must be the *live* plastic weight table (ELL-padded view
-    of the flat plastic weights) and ``pmask`` its plastic-synapse mask,
-    both ``[N+1, K]``; ``spk_prev`` is ``spiked_prev`` as f32 (the trace
-    bump of the step whose spikes are being delivered).  Returns
-    ``(ring', weights', V', I_ex', I_in', refrac', spiked, x_pre',
-    x_post')`` — potentiation and clipping stay in XLA
+    of the flat plastic weights) and ``pmask`` its plastic-synapse mask
+    (bool or int32), both shaped like ``targets``; ``spk_prev`` is
+    ``spiked_prev`` as f32 (the trace bump of the step whose spikes are
+    being delivered).  Returns ``(ring', weights', V', I_ex', I_in',
+    refrac', spiked, x_pre', x_post')`` with ``weights'`` shaped like
+    ``weights`` — potentiation and clipping stay in XLA
     (``repro.core.plasticity.stdp_pot_clip``).
     """
-    s_budget = ids.shape[0]
-    assert s_budget >= 1, "fused step needs spike_budget >= 1"
-    k = targets.shape[1]
-    assert k % block_k == 0 and weights.shape[1] == k \
-        and pmask.shape[1] == k, "plastic fused step needs pre-padded ELL"
-    n_lanes = -(-n_cols // 128) * 128
-    ring2 = jnp.pad(ring.reshape(2 * d_bins, n_cols),
-                    ((0, 0), (0, n_lanes - n_cols)))
-    meta = jnp.stack([jnp.asarray(t_prev, jnp.int32),
-                      jnp.full((), n_exc, jnp.int32)])
-    fvec = [_pad_lanes(x, n_lanes) for x in (V, I_ex, I_in)]
-    ivec = _pad_lanes(refrac, n_lanes)
-    dvec = [_pad_lanes(x, n_lanes)
-            for x in (ext_ex, i_dc, x_pre, x_post, spk_prev)]
-
-    last = s_budget - 1
-    row = pl.BlockSpec((1, block_k),
-                       lambda s, kb, ids, meta: (ids[jnp.minimum(s, last)],
-                                                 kb))
-    vec = pl.BlockSpec((n_lanes,), lambda s, kb, ids, meta: (0,))
-    full = pl.BlockSpec((2 * d_bins, n_lanes),
-                        lambda s, kb, ids, meta: (0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(s_budget + 1, k // block_k),
-        in_specs=[row, row, row, row, full,
-                  vec, vec, vec, vec, vec, vec, vec, vec, vec],
-        out_specs=[full, row, vec, vec, vec, vec, vec, vec, vec],
-    )
-    outs = pl.pallas_call(
-        functools.partial(_kernel_plastic, d_bins=d_bins, block_k=block_k,
-                          s_budget=s_budget, n_lanes=n_lanes, prop=prop,
-                          dep_coef=dep_coef, decay_p=decay_p,
-                          decay_m=decay_m),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((2 * d_bins, n_lanes), jnp.float32),
-            jax.ShapeDtypeStruct(weights.shape, jnp.float32),
-            jax.ShapeDtypeStruct((n_lanes,), jnp.float32),
-            jax.ShapeDtypeStruct((n_lanes,), jnp.float32),
-            jax.ShapeDtypeStruct((n_lanes,), jnp.float32),
-            jax.ShapeDtypeStruct((n_lanes,), jnp.int32),
-            jax.ShapeDtypeStruct((n_lanes,), jnp.bool_),
-            jax.ShapeDtypeStruct((n_lanes,), jnp.float32),
-            jax.ShapeDtypeStruct((n_lanes,), jnp.float32),
-        ],
+    tables = (pad_table(targets, block_k, n_cols - 1),
+              pad_table(weights, block_k, 0.0),
+              pad_table(dbins, block_k, 1),
+              pad_table(pmask.astype(jnp.int32), block_k, 0))
+    w_shape = jax.ShapeDtypeStruct(tables[1].shape, jnp.float32)
+    ring_out, (w_out, Vo, iexo, iino, refo, spk, xpreo, xposto) = _call(
+        functools.partial(_kernel_plastic, prop=prop, dep_coef=dep_coef,
+                          decay_p=decay_p, decay_m=decay_m),
+        ids, t_prev, n_exc, tables, ring,
+        [V, I_ex, I_in, refrac, ext_ex, i_dc, x_pre, x_post, spk_prev], 7,
+        [w_shape],
         # ring -> ring', live weights -> depressed weights (input indices
         # count the 2 prefetch operands)
-        input_output_aliases={6: 0, 3: 1},
-        interpret=interpret,
-    )(ids, meta, targets, weights, dbins, pmask, ring2, *fvec, ivec,
-      *dvec)
-    ring_out, w_out, Vo, iexo, iino, refo, spk, xpreo, xposto = outs
-    ring_out = ring_out.reshape(d_bins, 2, n_lanes)[:, :, :n_cols]
-    return (ring_out, w_out, Vo[:n], iexo[:n], iino[:n], refo[:n],
-            spk[:n], xpreo[:n], xposto[:n])
+        {6: 0, 3: 1}, d_bins=d_bins, n_cols=n_cols, block_k=block_k,
+        interpret=interpret)
+    rows, k = weights.shape
+    return (ring_out, w_out[:rows, :k], _vec(Vo, n), _vec(iexo, n),
+            _vec(iino, n), _vec(refo, n), _vec(spk, n) != 0,
+            _vec(xpreo, n), _vec(xposto, n))

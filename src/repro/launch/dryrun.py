@@ -161,7 +161,6 @@ def run_cell(arch: str, shape_name: str, mesh_name: str,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
     hlo = compiled.as_text()
     # trip-count-aware analysis (cost_analysis counts scan bodies once)
     from repro.perf.hlo_analysis import analyze_hlo

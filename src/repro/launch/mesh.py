@@ -10,13 +10,9 @@ import jax
 
 
 def make_mesh_auto(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the installed jax
-    supports them (>= 0.5); plain mesh otherwise (Auto is the default)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis of type Auto."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -31,9 +27,3 @@ def make_host_mesh() -> jax.sharding.Mesh:
     n = len(jax.devices())
     return make_mesh_auto((1, n), ("data", "model"))
 
-
-# TPU v5e hardware constants used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s per chip
-HBM_BW = 819e9                # B/s per chip
-ICI_BW = 50e9                 # B/s per link (~per chip per direction)
-HBM_BYTES = 16 * 2 ** 30      # 16 GiB per chip

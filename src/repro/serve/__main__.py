@@ -17,6 +17,8 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.launch.runtime import setup_jax
+
 
 def _smoke(scenario: str, warm_ms: float | None) -> int:
     from repro.serve.http import ServeClient, SimServer
@@ -70,6 +72,7 @@ def _smoke(scenario: str, warm_ms: float | None) -> int:
 
 
 def main(argv=None) -> int:
+    setup_jax()
     ap = argparse.ArgumentParser(
         description="repro session server (stdlib HTTP/JSON front end)")
     ap.add_argument("--host", default="127.0.0.1")

@@ -57,8 +57,20 @@ class ReferenceSpec:
                 f"{len(self.populations)} populations")
 
 
-def microcircuit_reference(rate_rel_tol: float = 0.5,
-                           rate_abs_tol: float = 1.0,
+RATE_REL_TOL = 0.5
+RATE_ABS_TOL = 1.0
+
+
+def rate_band(rate_hz: float, rel_tol: float = RATE_REL_TOL,
+              abs_tol: float = RATE_ABS_TOL) -> Band:
+    """Accepted rates around ``rate_hz``:
+    ``rate_hz * (1 -+ rel_tol) -+ abs_tol``, floored at 0."""
+    return Band(max(0.0, rate_hz * (1 - rel_tol) - abs_tol),
+                rate_hz * (1 + rel_tol) + abs_tol)
+
+
+def microcircuit_reference(rate_rel_tol: float = RATE_REL_TOL,
+                           rate_abs_tol: float = RATE_ABS_TOL,
                            cv_band: Tuple[float, float] = (0.3, 1.5),
                            corr_band: Tuple[float, float] = (-0.05, 0.1),
                            sync_band: Tuple[float, float] = (0.0, 8.0),
@@ -72,10 +84,8 @@ def microcircuit_reference(rate_rel_tol: float = 0.5,
     admits the regularisation that DC compensation introduces at small
     scales (the full-scale AI band is ~[0.7, 1.2]).
     """
-    bands = tuple(
-        Band(max(0.0, r * (1 - rate_rel_tol) - rate_abs_tol),
-             r * (1 + rate_rel_tol) + rate_abs_tol)
-        for r in P.FULL_MEAN_RATES)
+    bands = tuple(rate_band(r, rate_rel_tol, rate_abs_tol)
+                  for r in P.FULL_MEAN_RATES)
     return ReferenceSpec(
         populations=P.POPULATIONS,
         rate_hz=bands,

@@ -237,7 +237,13 @@ def test_ell_strategy_zero_spike_step_full_cycle(tiny_c):
 def test_ell_table_rows_are_lane_padded(tiny_c):
     tables = dlv.get_strategy("ell").prepare(tiny_c, SimConfig())
     assert tables.targets.shape[1] % dlv.EllDelivery.block_k == 0
-    assert tables.targets.shape[0] == tiny_c.n_total + 1   # sentinel row
+    n = tiny_c.n_total
+    rows = tables.targets.shape[0]
+    assert rows % dlv.EllDelivery.row_tile == 0
+    assert n + 1 <= rows < n + 1 + dlv.EllDelivery.row_tile
+    # row N and every padding row after it are sentinel rows
+    np.testing.assert_array_equal(np.asarray(tables.targets[n:]), n)
+    np.testing.assert_array_equal(np.asarray(tables.weights[n:]), 0.0)
 
 
 # ---------------------------------------------------------------------------

@@ -234,6 +234,38 @@ def test_fused_kernel_multi_step_trajectory():
                                   np.asarray(w_neuron.V))
 
 
+def test_fused_plastic_kernel_depresses_rows_sharing_a_tile():
+    """Spiking rows in one 8-row table tile each keep their depression:
+    the kernel moves whole tiles, so a later row's write-back must not
+    restore an earlier row's weights."""
+    from repro.kernels.lif_deliver import lif_deliver_plastic_pallas
+    n, k, d_bins, dep_coef = 40, 128, 5, 0.5
+    rng = np.random.default_rng(0)
+    targets = rng.integers(0, n, (n + 1, k)).astype(np.int32)
+    targets[n] = n
+    weights = rng.normal(size=(n + 1, k)).astype(np.float32)
+    weights[n] = 0.0
+    dbins = rng.integers(1, d_bins, (n + 1, k)).astype(np.int32)
+    pmask = np.ones((n + 1, k), np.int32)
+    pmask[n] = 0
+    x_post = rng.uniform(size=n).astype(np.float32)
+    rows = [0, 1, 2, 9]                  # three in tile 0, one in tile 1
+    zeros = jnp.zeros(n, jnp.float32)
+    out = lif_deliver_plastic_pallas(
+        jnp.asarray(rows + [n, n], jnp.int32), jnp.asarray(targets),
+        jnp.asarray(weights), jnp.asarray(dbins), jnp.asarray(pmask),
+        jnp.zeros((d_bins, 2, n + 1)), zeros - 65.0, zeros, zeros,
+        jnp.zeros(n, jnp.int32), zeros, zeros, zeros, jnp.asarray(x_post),
+        zeros, jnp.asarray(3, jnp.int32), d_bins=d_bins, n_cols=n + 1, n=n,
+        n_exc=30, prop=Propagators.make(NeuronParams(), 0.1),
+        dep_coef=dep_coef, decay_p=0.9, decay_m=0.9, interpret=True)
+    want = weights.copy()
+    x_pad = np.append(x_post, np.float32(0.0))
+    for r in rows:
+        want[r] = weights[r] - dep_coef * x_pad[targets[r]]
+    np.testing.assert_array_equal(np.asarray(out[1]), want)
+
+
 # ---------------------------------------------------------------------------
 # End-to-end bitwise pins at scale 0.05, across backends
 # ---------------------------------------------------------------------------
