@@ -1,0 +1,486 @@
+#!/usr/bin/env python3
+"""Run one benchmark cell once on the chip this process finds.
+
+    python3 chipbench/run.py --workload pd14_full.scan20 --seed 7 \\
+        --seconds 45 --trace 0
+
+A cell is a ``workloads`` entry of ``BENCHMARK.json``: a configuration
+(``chipbench/configs/<config>.json``) under a traffic mix
+(``chipbench/mixes/<traffic>.json``), with the limits of its correctness
+comparison in ``chipbench/cells/<cell>.json``.  Every metric is read by
+``chipbench/metrics/<metric>.py``.  A new cell, configuration, mix or
+metric is new files and new ``BENCHMARK.json`` entries; nothing here
+names one.
+
+One run, in one process:
+
+* set-up (``setup_s``, from process start to the first timed call): the
+  network instance is drawn on the device from the configuration's fixed
+  ``network_seed`` (:mod:`chipbench.netgen`); the program's ``Simulator``
+  is built on the chip's kernels (``kernels`` from the configuration) with
+  the dynamics key from ``--seed``; the mix's one call length is compiled
+  (JAX's persistent cache lives in ``chipbench/.cache/jax``); one call
+  runs, which includes the configuration's presim;
+* the window: ``Simulator.run(chunk_ms)`` again and again until
+  ``--seconds`` have passed, each call ending with its per-step population
+  spike counts in host memory, each under a zero-compile guard.  A call
+  that raises, compiles or drops spikes is ``failed``;
+* the check: sampled calls (drawn from the seed) are rerun by the plain
+  reference from the state the program started them in, once the
+  program's device memory is freed (:mod:`chipbench.compare`).
+
+``--trace 1`` profiles the window and reports the per-layer metrics
+instead of the end-to-end ones.  The last line of standard output is the
+result as one JSON object; the compared numbers and their limits are the
+last lines of standard error.  Without a TPU, or with fewer chips than
+the cell asks for, it exits with code 3 and prints no result.
+"""
+from __future__ import annotations
+
+import time
+
+T_START = time.perf_counter()
+
+import argparse  # noqa: E402
+import contextlib  # noqa: E402
+import gc  # noqa: E402
+import importlib.util  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import pathlib  # noqa: E402
+import random  # noqa: E402
+import resource  # noqa: E402
+import shutil  # noqa: E402
+import sys  # noqa: E402
+import tempfile  # noqa: E402
+import traceback  # noqa: E402
+from types import SimpleNamespace  # noqa: E402
+from typing import List, NamedTuple, Optional  # noqa: E402
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+BENCH = ROOT / "chipbench"
+CACHE = BENCH / ".cache" / "jax"
+for _p in (ROOT, ROOT / "src"):
+    if str(_p) not in sys.path:
+        sys.path.insert(0, str(_p))
+
+
+class NoChip(RuntimeError):
+    """No TPU, or fewer chips than the cell asks for."""
+
+
+class Call(NamedTuple):
+    t0: float
+    t1: float
+    steps: int
+    counts: object          # [steps, P] int32, host
+
+
+def log(msg: str) -> None:
+    print(msg, file=sys.stderr, flush=True)
+
+
+def process_age() -> float:
+    """Seconds since this process started (falls back to module import)."""
+    try:
+        with open("/proc/self/stat") as f:
+            start = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            up = float(f.read().split()[0])
+        return up - start / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return time.perf_counter() - T_START
+
+
+def host_rss_gib() -> float:
+    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 2 ** 20
+
+
+# ---------------------------------------------------------------------------
+# What a cell is, found by name
+# ---------------------------------------------------------------------------
+
+def load_cell(name: str, root: pathlib.Path = ROOT) -> SimpleNamespace:
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    by = lambda key, n: next(e for e in spec[key] if e["name"] == n)
+    try:
+        wl = by("workloads", name)
+    except StopIteration:
+        raise SystemExit(f"no workload {name!r} in BENCHMARK.json") from None
+    bench = root / "chipbench"
+    applies = lambda m: name in m.get("workloads", [name])
+    return SimpleNamespace(
+        name=name, workload=wl, chips=int(wl["chips"]),
+        cfg=json.loads((bench / "configs" / f"{wl['config']}.json")
+                       .read_text()),
+        mix=json.loads((bench / "mixes" / f"{wl['traffic']}.json")
+                       .read_text()),
+        limits=json.loads((bench / "cells" / f"{name}.json")
+                          .read_text())["limits"],
+        end_to_end=[m for m in spec["end_to_end"] if applies(m)],
+        per_layer=[m for m in spec["per_layer"] if applies(m)],
+        bench=bench)
+
+
+def metric_reader(bench: pathlib.Path, name: str):
+    path = bench / "metrics" / f"{name}.py"
+    mod_spec = importlib.util.spec_from_file_location(
+        f"chipbench_metric_{name.replace('.', '_')}", path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod.read
+
+
+# ---------------------------------------------------------------------------
+# JAX, the chip, the program
+# ---------------------------------------------------------------------------
+
+class CompileCounter:
+    """Counts XLA compiles and persistent-cache loads, process-wide."""
+
+    def __init__(self):
+        import jax
+        self.compiles = 0
+        self.cache_hits = 0
+        self.compile_s = 0.0
+
+        def on_duration(event, secs, **_):
+            if event == "/jax/core/compile/backend_compile_duration":
+                self.compiles += 1
+                self.compile_s += secs
+
+        def on_event(event, **_):
+            if event == "/jax/compilation_cache/cache_hits":
+                self.cache_hits += 1
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        jax.monitoring.register_event_listener(on_event)
+
+    def total(self) -> int:
+        return self.compiles + self.cache_hits
+
+
+def setup_jax(cache: pathlib.Path = CACHE):
+    """Persistent compile cache at a fixed path inside the checkout; every
+    program is cached, so only a checkout's first run compiles."""
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(cache)
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(cache))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return jax
+
+
+def require_chips(n: int):
+    import jax
+    devs = jax.devices()
+    if devs[0].platform != "tpu" or len(devs) < n:
+        raise NoChip(f"this cell needs {n} TPU chip(s); JAX reports "
+                     f"{len(devs)} {devs[0].platform} device(s)")
+    return devs[:n]
+
+
+def dynamics_key(seed: int):
+    """The threefry key of ``--seed`` (any whole number; 64 bits kept)."""
+    import jax.numpy as jnp
+    s = int(seed) % 2 ** 64
+    return jnp.array([s >> 32, s & 0xFFFFFFFF], dtype=jnp.uint32)
+
+
+def connectome(net, cfg: dict):
+    """The network instance as the program's input type."""
+    import numpy as np
+
+    from repro.core.connectivity import Connectome
+    m = net.model
+    return Connectome(
+        n_total=m.n_total, n_exc=m.n_exc, pop_sizes=m.n_pop,
+        pop_offsets=m.offsets, targets=net.targets, weights=net.weights,
+        dbins=net.dbins, out_degree=net.out_degree,
+        n_synapses=int(net.out_degree.sum()), d_max_bins=m.d_max_bins,
+        k_ext=m.k_ext[net.pop_of].astype(np.float32),
+        i_dc=m.i_dc[net.pop_of].astype(np.float32), w_ext=float(m.w_ext),
+        v0_mean=m.v0_mean[net.pop_of].astype(np.float32),
+        v0_sd=m.v0_sd[net.pop_of].astype(np.float32), pop_of=net.pop_of,
+        k_scaling=float(cfg["k_scaling"]))
+
+
+def simulator(cfg: dict, conn, seed: int, state_dtype: Optional[str] = None):
+    """The program's session, on the kernels the configuration names."""
+    import jax.numpy as jnp
+
+    from repro.api import Simulator
+    from repro.configs.microcircuit import MicrocircuitConfig
+    from repro.core.params import NeuronParams
+    mc = MicrocircuitConfig(
+        n_scaling=cfg["n_scaling"], k_scaling=cfg["k_scaling"],
+        dt=cfg["dt_ms"], t_presim=cfg["t_presim_ms"],
+        strategy=cfg["strategy"], kernels=cfg["kernels"])
+    return Simulator(
+        mc, connectome=conn, probes=("pop_counts",),
+        plasticity=cfg.get("plasticity"),
+        neuron=NeuronParams(**cfg["neuron"]), key=dynamics_key(seed),
+        state_dtype=getattr(jnp, state_dtype or cfg["state_dtype"]))
+
+
+def export_state(state, n: int, k: int) -> dict:
+    """The program's state as the reference's leaves (device arrays)."""
+    sim, ps = (state if isinstance(state, tuple)
+               and not hasattr(state, "neuron") else (state, None))
+    out = dict(V=sim.neuron.V, I_ex=sim.neuron.I_ex, I_in=sim.neuron.I_in,
+               refrac=sim.neuron.refrac, ring=sim.ring, t=sim.t,
+               key=sim.key)
+    if ps is not None:
+        out.update(w=ps.weights[:(n + 1) * k].reshape(n + 1, k),
+                   x_pre=ps.x_pre, x_post=ps.x_post)
+    return out
+
+
+# ---------------------------------------------------------------------------
+# The window
+# ---------------------------------------------------------------------------
+
+class Sampler:
+    """Keeps ``m`` calls drawn uniformly from the window (reservoir,
+    seeded): the program's state before and after each, as it handed them
+    back (on the device, untouched), and its counts."""
+
+    def __init__(self, m: int, seed: int):
+        self.m, self.rng, self.seen, self.kept = m, random.Random(seed), 0, []
+
+    def offer(self, before, after, counts) -> None:
+        self.seen += 1
+        item = (before, after, counts)
+        if len(self.kept) < self.m:
+            self.kept.append(item)
+        else:
+            j = self.rng.randrange(self.seen)
+            if j < self.m:
+                self.kept[j] = item
+
+
+def timed_call(sim, chunk_ms: float, counter: CompileCounter, ovf0: int):
+    """One call of the session, to its counts in host memory.  ``ovf0`` is
+    the spike overflow the call before reported.  Returns ``(t0, t1,
+    counts, overflow, ok)``."""
+    import jax
+    import numpy as np
+
+    from repro.analysis import RecompileGuard
+    before = counter.total()
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation("chipbench.call"), \
+            RecompileGuard(0, caches=sim.backend.caches(),
+                           what="a timed call"):
+        res = sim.run(chunk_ms)
+        counts = np.asarray(res.data["pop_counts"])
+    t1 = time.perf_counter()
+    ok = counter.total() == before and res.overflow == ovf0
+    return t0, t1, counts, res.overflow, ok
+
+
+def window(sim, mix: dict, seconds: float, sampler: Sampler,
+           counter: CompileCounter, ovf: int):
+    """Calls until ``seconds`` have passed, from ``ovf``, the overflow
+    after set-up.  Returns (calls, attempted, failed, (start, end)): the
+    window runs from the first call's start to the last call's end, and
+    everything between calls counts in it."""
+    calls: List[Call] = []
+    attempted = failed = 0
+    w0 = time.perf_counter()
+    while time.perf_counter() - w0 < seconds:
+        before = sim.state
+        attempted += 1
+        try:
+            t0, t1, counts, ovf, ok = timed_call(sim, mix["chunk_ms"],
+                                                 counter, ovf)
+        except Exception:                  # a failed call ends the window
+            log(traceback.format_exc())
+            failed += 1
+            break
+        failed += not ok
+        calls.append(Call(t0, t1, counts.shape[0], counts))
+        sampler.offer(before, sim.state, counts)
+    span = (calls[0].t0, calls[-1].t1) if calls else (w0, w0)
+    return calls, attempted, failed, span
+
+
+def rerun(tb, c, before, after, counts) -> dict:
+    """The readings of one call: the reference from ``before``."""
+    import jax
+    import numpy as np
+
+    from chipbench import compare, reference
+    ref_after, ref_counts = reference.chunk(tb, before, counts.shape[0], c)
+    return compare.sample_gaps(
+        jax.tree.map(np.asarray, before), jax.tree.map(np.asarray, after),
+        counts, jax.tree.map(np.asarray, ref_after), np.asarray(ref_counts))
+
+
+def check(samples, net, cfg: dict, limits: dict, tb=None) -> dict:
+    """Rerun each sampled call with the plain reference; the compared
+    numbers and the verdict."""
+    from chipbench import compare, reference
+    t0 = time.perf_counter()
+    tb = reference.tables(net, cfg) if tb is None else tb
+    c = reference.consts(net, cfg)
+    n, k = net.targets.shape
+    readings = [rerun(tb, c, export_state(a, n, k), export_state(b, n, k),
+                      counts) for a, b, counts in samples]
+    numbers = compare.combine(readings)
+    log(f"check: {len(readings)} call(s) rerun by the reference in "
+        f"{time.perf_counter() - t0:.3f} s; per call: "
+        + "; ".join(" ".join(f"{k}={v:.6g}" for k, v in r.items())
+                    for r in readings))
+    return {"numbers": numbers, "readings": readings,
+            "correct": compare.verdict(numbers, limits)}
+
+
+def device_info(devs) -> dict:
+    peak = 0
+    for d in devs:
+        stats = d.memory_stats() or {}
+        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs), "memory_peak_bytes": peak}
+
+
+# ---------------------------------------------------------------------------
+# One run
+# ---------------------------------------------------------------------------
+
+def session(cfg: dict, mix: dict, net, seed: int, counter: CompileCounter,
+            program_dtype: Optional[str] = None):
+    """The program's session, compiled for the mix's call and past its
+    presim and one untimed call; returns it with its spike overflow."""
+    t = time.perf_counter()
+    sim = simulator(cfg, connectome(net, cfg), seed, program_dtype)
+    log(f"setup: simulator backend={sim.backend.name} "
+        f"kernels={sim.sim_config.kernels.describe()} "
+        f"state_dtype={sim.sim_config.state_dtype.__name__} "
+        f"spike_budget={sim.sim_config.spike_budget} "
+        f"build_s={time.perf_counter() - t:.3f} "
+        f"host_rss_peak_gib={host_rss_gib():.3f}")
+    return sim, warm(sim, cfg, mix, counter)
+
+
+def warm(sim, cfg: dict, mix: dict, counter: CompileCounter) -> int:
+    """Compile the mix's call, run the presim and one untimed call;
+    returns the spike overflow after it."""
+    t = time.perf_counter()
+    c0 = (counter.compiles, counter.cache_hits)
+    sim.warmup(mix["chunk_ms"])
+    first = timed_call(sim, mix["chunk_ms"], counter,   # presim + one call
+                       sim.backend.overflow(sim.state))
+    log(f"setup: warm-up and presim {cfg['t_presim_ms']} ms: "
+        f"{time.perf_counter() - t:.3f} s, compiles="
+        f"{counter.compiles - c0[0]} (compile_s={counter.compile_s:.3f}) "
+        f"cache_hits={counter.cache_hits - c0[1]} "
+        f"first_call_s={first[1] - first[0]:.3f}")
+    return first[3]
+
+
+def make_network(cfg: dict):
+    from chipbench import netgen
+    t = time.perf_counter()
+    net = netgen.network(cfg)
+    log(f"setup: network n={net.model.n_total} k={net.targets.shape[1]} "
+        f"synapses={int(net.out_degree.sum())} "
+        f"gen_s={time.perf_counter() - t:.3f} "
+        f"host_rss_peak_gib={host_rss_gib():.3f}")
+    return net
+
+
+def run_cell(cell, seed: int, seconds: float, trace: bool,
+             devices=None) -> dict:
+    """Set up, measure, check; return the result object.  ``devices``
+    skips the look for chips (the tests pass the CPU)."""
+    from chipbench import peaks
+    from chipbench import trace as T
+    if devices is None:
+        devs = require_chips(cell.chips)
+        jax = setup_jax()
+    else:
+        import jax
+        devs = list(devices)
+    counter = CompileCounter()
+    cfg, mix = cell.cfg, cell.mix
+    net = make_network(cfg)
+    sim, ovf = session(cfg, mix, net, seed, counter)
+
+    sampler = Sampler(int(mix["check_chunks"]), seed)
+    setup_s = process_age()
+    tmp = tempfile.mkdtemp(prefix="chipbench-trace-") if trace else None
+    if trace:
+        jax.profiler.start_trace(tmp)
+    calls, attempted, failed, span = window(sim, mix, seconds, sampler,
+                                            counter, ovf)
+    if trace:
+        jax.profiler.stop_trace()
+    device = device_info(devs)
+    log(f"window: {len(calls)} call(s), {failed} failed, "
+        f"memory_peak_bytes={device['memory_peak_bytes']}")
+
+    del sim
+    gc.collect()
+    verdict = check(sampler.kept, net, cfg, cell.limits)
+
+    kind = devs[0].device_kind
+    run = SimpleNamespace(calls=calls, span=span, net=net, cfg=cfg,
+                          mix=mix, setup_s=setup_s, trace=None,
+                          peaks=(peaks.peaks_for(kind) if kind in peaks.PEAKS
+                                 else None))
+    wanted = cell.per_layer if trace else cell.end_to_end
+    result = {"correct": bool(verdict["correct"]), "attempted": attempted,
+              "failed": failed}
+    if trace:
+        run.trace = T.load(tmp, [d.id for d in devs])
+        shutil.rmtree(tmp, ignore_errors=True)
+        lo, hi = T.window(run.trace) or (0.0, 0.0)
+        device["busy_s"] = T.busy(run.trace, lo, hi) * 1e-9
+        device["window_s"] = (hi - lo) * 1e-9
+    metrics = {}
+    for m in wanted:
+        value = metric_reader(cell.bench, m["name"])(run)
+        if value is not None:
+            metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
+    result["metrics"] = metrics
+    result["device"] = device
+    if trace:
+        ops = T.op_seconds(run.trace, lo, hi)
+        result["breakdown"] = {
+            "device_ops": [[k, v] for k, v in sorted(
+                ops.items(), key=lambda kv: -kv[1])[:10]],
+            "idle_gaps": T.idle_gaps(run.trace, lo, hi)}
+    result["checks"] = {k: {"value": v, "limit": cell.limits[k]}
+                        for k, v in verdict["numbers"].items()
+                        if k in cell.limits}
+    result["checks"]["ref_overflow"] = {
+        "value": verdict["numbers"]["ref_overflow"], "limit": 0}
+    return result
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(
+        description=__doc__.splitlines()[0],
+        formatter_class=argparse.RawDescriptionHelpFormatter)
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    args = ap.parse_args(argv)
+    cell = load_cell(args.workload)
+    try:
+        result = run_cell(cell, args.seed, args.seconds, bool(args.trace))
+    except NoChip as e:
+        log(f"chipbench: {e}")
+        return 3
+    for name, c in result["checks"].items():
+        log(f"check {name} = {c['value']!r} (limit {c['limit']!r})")
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    with contextlib.suppress(BrokenPipeError):
+        sys.exit(main())
