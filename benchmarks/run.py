@@ -3,7 +3,6 @@
 
   table1_rtf        — paper Table I (RTF + energy/synaptic event)
   strong_scaling    — paper Fig. 1b top (RTF vs scale/resources)
-  phase_breakdown   — paper Fig. 1b bottom (update/deliver fractions)
   delivery_ablation — beyond-paper: event vs dense vs gated-kernel delivery
   roofline          — deliverable (g): per-cell roofline terms from dry-run
   serve_throughput  — session-server load: sessions/sec, p50/p99 latency
@@ -17,12 +16,11 @@ import traceback
 
 
 def main() -> None:
-    from benchmarks import (delivery_ablation, phase_breakdown, roofline,
-                            serve_throughput, strong_scaling, table1_rtf)
+    from benchmarks import (delivery_ablation, roofline, serve_throughput,
+                            strong_scaling, table1_rtf)
     suites = {
         "table1_rtf": table1_rtf.main,
         "strong_scaling": strong_scaling.main,
-        "phase_breakdown": phase_breakdown.main,
         "delivery_ablation": delivery_ablation.main,
         "roofline": roofline.main,
         "serve_throughput": lambda: serve_throughput.main([]),

@@ -46,11 +46,12 @@ from repro.serve.compile_cache import ExecutableCache
 from repro.core import distributed as DD
 from repro.core import stimulus as stim
 from repro.core.connectivity import Connectome
-from repro.core.engine import (SimConfig, SimState, _external_drive,
-                               deliver_phase, fused_update_phase, init_state,
+from repro.core.engine import (SimConfig, SimState, deliver_phase,
+                               fused_drive, fused_update_phase, init_state,
                                prepare_network, resolve_sim_config,
                                update_phase)
 from repro.core.neuron import NeuronParams, Propagators
+from repro.perf import scopes
 
 
 def _force_split_step(cfg: SimConfig) -> SimConfig:
@@ -284,7 +285,9 @@ class FusedBackend(Backend):
             fn = self._compiled(*key)
             _, stream_probes = split_probes(key[1])
             carries = self._stream_carries(stream_probes, None)
-            return fn.lower(*self._args(state), carries).compile()
+            compiled = fn.lower(*self._args(state), carries).compile()
+            scopes.record(compiled)
+            return compiled
         self._aot.get_or_build(key, build)
 
     def run(self, state, n_steps, probes, stream=None):
@@ -324,7 +327,9 @@ class FusedBackend(Backend):
             fn = self._batched(n_steps, probes)
             _, stream_probes = split_probes(probes)
             carries = self._batch_carries(stream_probes, None, n_trials)
-            return fn.lower(*self._args(states), carries).compile()
+            compiled = fn.lower(*self._args(states), carries).compile()
+            scopes.record(compiled)
+            return compiled
         self._aot.get_or_build((n_trials, n_steps, probes), build)
 
     def is_warm_batch(self, n_trials, n_steps, probes):
@@ -376,8 +381,10 @@ class FusedBackend(Backend):
         step_probes, stream_probes = split_probes(probes)
 
         def stream_update(scs, spiked, ctx):
-            return tuple(p.update(sc, ctx if p.needs == "ctx" else spiked)
-                         for p, sc in zip(stream_probes, scs))
+            with scopes.scope("probes"):
+                return tuple(
+                    p.update(sc, ctx if p.needs == "ctx" else spiked)
+                    for p, sc in zip(stream_probes, scs))
 
         if self._bound is None and fused:
             strategy = dlv.get_strategy(cfg.strategy)
@@ -397,9 +404,10 @@ class FusedBackend(Backend):
                     step, ((state, spk0), carries), None, length=n_steps)
                 # epilogue: the rotated loop leaves the last step's spikes
                 # undelivered — land them at their true phase t-1
-                ring, ovf = strategy.deliver(
-                    state.ring, net.tables, spk_last, state.t - 1, n_exc,
-                    cfg)
+                with scopes.scope("deliver"):
+                    ring, ovf = strategy.deliver(
+                        state.ring, net.tables, spk_last, state.t - 1,
+                        n_exc, cfg)
                 state = SimState(state.neuron, ring, state.t, state.key,
                                  state.overflow + ovf)
                 return state, carries, outs
@@ -432,34 +440,34 @@ class FusedBackend(Backend):
                 # the kernel reads the mask as int32 tiles shaped like the
                 # ELL tables (ELL pad, no reorder)
                 rows_ell, k_ell = net.tables.targets.shape
-                pmask = jnp.pad(tables.plastic_out.astype(jnp.int32),
-                                ((0, rows_ell - (n + 1)),
-                                 (0, k_ell - k_out)))
+                with scopes.scope("plasticity"):
+                    pmask = jnp.pad(tables.plastic_out.astype(jnp.int32),
+                                    ((0, rows_ell - (n + 1)),
+                                     (0, k_ell - k_out)))
 
                 def step(carry, _):
                     (sim, ps, spk_prev), scs = carry
-                    key, ext_ex, i_dc = _external_drive(
-                        sim, net, cfg, c.w_ext, sim.ring.dtype, drive)
-                    if ext_ex is None:
-                        ext_ex = jnp.zeros((n,), sim.ring.dtype)
-                    i_dc = jnp.broadcast_to(i_dc, (n,)).astype(
-                        sim.ring.dtype)
-                    live = strategy.live_tables(
-                        net.tables, bound.weight_view(ps, tables))
-                    (neuron, ring, spiked, w_out, xpre_o, xpost_o, ids,
-                     ovf) = kops.lif_deliver_plastic(
-                        sim.neuron, sim.ring, sim.t, spk_prev, live,
-                        live.weights, pmask, ps.x_pre, ps.x_post, prop,
-                        ext_ex, i_dc, n_exc=n_exc,
-                        spike_budget=cfg.spike_budget, dep_coef=dep_coef,
-                        decay_p=decay_p, decay_m=decay_m,
-                        interpret=pol.interpret)
-                    w_flat = jnp.concatenate(
-                        [w_out[:n + 1, :k_out].reshape(-1),
-                         ps.weights[(n + 1) * k_out:]])
-                    w_flat = PL.stdp_pot_clip(w_flat, ps.x_pre, ids,
-                                              tables, bound.cfg,
-                                              bound.clip_mask)
+                    key, ext_ex, i_dc = fused_drive(sim, net, cfg, c.w_ext,
+                                                    n, drive)
+                    with scopes.scope("plasticity"):
+                        live = strategy.live_tables(
+                            net.tables, bound.weight_view(ps, tables))
+                    with scopes.scope("fused_step"):
+                        (neuron, ring, spiked, w_out, xpre_o, xpost_o, ids,
+                         ovf) = kops.lif_deliver_plastic(
+                            sim.neuron, sim.ring, sim.t, spk_prev, live,
+                            live.weights, pmask, ps.x_pre, ps.x_post, prop,
+                            ext_ex, i_dc, n_exc=n_exc,
+                            spike_budget=cfg.spike_budget,
+                            dep_coef=dep_coef, decay_p=decay_p,
+                            decay_m=decay_m, interpret=pol.interpret)
+                    with scopes.scope("plasticity"):
+                        w_flat = jnp.concatenate(
+                            [w_out[:n + 1, :k_out].reshape(-1),
+                             ps.weights[(n + 1) * k_out:]])
+                        w_flat = PL.stdp_pot_clip(w_flat, ps.x_pre, ids,
+                                                  tables, bound.cfg,
+                                                  bound.clip_mask)
                     ps = PL.PlasticState(w_flat, xpre_o, xpost_o)
                     sim = SimState(neuron, ring, sim.t + 1, key,
                                    sim.overflow + ovf)
@@ -474,13 +482,16 @@ class FusedBackend(Backend):
                     step, ((sim0, ps0, spk0), carries), None,
                     length=n_steps)
                 # epilogue: deliver + full STDP step for the final spikes
-                live = strategy.live_tables(
-                    net.tables, bound.weight_view(ps, tables))
-                ring, ovf = strategy.deliver(
-                    state.ring, live, spk_last, state.t - 1, n_exc, cfg)
+                with scopes.scope("plasticity"):
+                    live = strategy.live_tables(
+                        net.tables, bound.weight_view(ps, tables))
+                with scopes.scope("deliver"):
+                    ring, ovf = strategy.deliver(
+                        state.ring, live, spk_last, state.t - 1, n_exc, cfg)
                 state = SimState(state.neuron, ring, state.t, state.key,
                                  state.overflow + ovf)
-                ps = bound.step(ps, tables, spk_last)
+                with scopes.scope("plasticity"):
+                    ps = bound.step(ps, tables, spk_last)
                 return (state, ps), carries, outs
         elif self._bound is not None:
             def runner(state, net, tables, carries):
@@ -488,13 +499,16 @@ class FusedBackend(Backend):
                     (sim, ps), scs = carry
                     sim, spiked = update_phase(sim, net, prop, cfg,
                                                c.w_ext, n, drive)
-                    live = strategy.live_tables(
-                        net.tables, bound.weight_view(ps, tables))
-                    ring, ovf = strategy.deliver(
-                        sim.ring, live, spiked, sim.t, n_exc, cfg)
+                    with scopes.scope("plasticity"):
+                        live = strategy.live_tables(
+                            net.tables, bound.weight_view(ps, tables))
+                    with scopes.scope("deliver"):
+                        ring, ovf = strategy.deliver(
+                            sim.ring, live, spiked, sim.t, n_exc, cfg)
                     sim = SimState(sim.neuron, ring, sim.t + 1, sim.key,
                                    sim.overflow + ovf)
-                    ps = bound.step(ps, tables, spiked)
+                    with scopes.scope("plasticity"):
+                        ps = bound.step(ps, tables, spiked)
                     ctx = ProbeContext(sim, spiked, net, n_pops,
                                        plastic=ps, plastic_mask=mask)
                     scs = stream_update(scs, spiked, ctx)

@@ -31,6 +31,8 @@ from typing import (TYPE_CHECKING, Callable, NamedTuple, Optional, Sequence,
 import jax
 import jax.numpy as jnp
 
+from repro.perf.scopes import scope
+
 if TYPE_CHECKING:
     from repro.core.engine import Network, SimState
     from repro.core.plasticity import PlasticState
@@ -53,7 +55,8 @@ class Probe:
     fn: Callable[[ProbeContext], jnp.ndarray]
 
     def __call__(self, ctx: ProbeContext) -> jnp.ndarray:
-        return self.fn(ctx)
+        with scope("probes"):
+            return self.fn(ctx)
 
 
 def pop_counts() -> Probe:

@@ -44,6 +44,10 @@ from repro.core.connectivity import Connectome, build_connectome
 from repro.core.engine import SimConfig
 from repro.core.neuron import NeuronParams
 
+#: A host span on the profiler's clock (about a microsecond when no
+#: profiler runs).
+_span = jax.profiler.TraceAnnotation
+
 
 class Simulator:
     """A simulation session: one network, one engine backend, many runs.
@@ -217,10 +221,12 @@ class Simulator:
         t = self.t_presim if presim_ms is None else float(presim_ms)
         if self._presim_done or t <= 0:
             return
-        self._state, _ = self.backend.run(self._state, self._steps(t), ())
-        jax.block_until_ready(self._state)
-        self._presim_done = True
-        self._check_overflow()
+        with _span("repro.presim"):
+            self._state, _ = self.backend.run(self._state, self._steps(t),
+                                              ())
+            jax.block_until_ready(self._state)
+            self._presim_done = True
+            self._check_overflow()
 
     # -- runs ---------------------------------------------------------------
 
@@ -231,42 +237,52 @@ class Simulator:
         The presim transient (``config.t_presim`` unless overridden) runs
         untimed and unrecorded once per session before the first timed
         phase, as in the paper's measurement protocol.
+
+        Under ``jax.profiler`` the call is a ``repro.run`` span holding
+        ``repro.presim`` (when the presim runs), ``repro.dispatch``,
+        ``repro.sync`` and ``repro.overflow``.
         """
         self._require_state("run")
-        pr = self.probes if probes is None else probes_mod.resolve(probes)
-        _, stream_probes = probes_mod.split_probes(pr)
-        self._maybe_presim(presim_ms)
-        n_steps = self._steps(t_ms)
-        timers0 = dict(self.timers)
-        stream_in = {p.name: self._stream_state.get(p.name)
-                     for p in stream_probes}
-        t0 = time.perf_counter()
-        self._state, data = self.backend.run(self._state, n_steps, pr,
-                                             stream=stream_in)
-        jax.block_until_ready((self._state, data))
-        wall = time.perf_counter() - t0
-        self._steps_done += n_steps
-        self._t_model_ms += n_steps * self.sim_config.dt
-        timers = {k: v - timers0.get(k, 0.0)
-                  for k, v in self.timers.items()}
-        streams = {}
-        for p in stream_probes:
-            carry = data.pop(p.name)
-            self._stream_state[p.name] = carry
-            # host-offloaded snapshot: chunked runs keep device memory flat
-            streams[p.name] = {"carry": jax.tree.map(np.asarray, carry),
-                               "meta": dict(p.meta)}
-        overflow = self._check_overflow()
-        return RunResult(
-            data=dict(data), t_model_ms=n_steps * self.sim_config.dt,
-            n_steps=n_steps, dt=self.sim_config.dt, wall_s=wall,
-            overflow=overflow, timers=timers, streams=streams,
-            _connectome=self.connectome)
+        with _span("repro.run"):
+            pr = (self.probes if probes is None
+                  else probes_mod.resolve(probes))
+            _, stream_probes = probes_mod.split_probes(pr)
+            self._maybe_presim(presim_ms)
+            n_steps = self._steps(t_ms)
+            timers0 = dict(self.timers)
+            stream_in = {p.name: self._stream_state.get(p.name)
+                         for p in stream_probes}
+            t0 = time.perf_counter()
+            with _span("repro.dispatch"):
+                self._state, data = self.backend.run(
+                    self._state, n_steps, pr, stream=stream_in)
+            with _span("repro.sync"):
+                jax.block_until_ready((self._state, data))
+            wall = time.perf_counter() - t0
+            self._steps_done += n_steps
+            self._t_model_ms += n_steps * self.sim_config.dt
+            timers = {k: v - timers0.get(k, 0.0)
+                      for k, v in self.timers.items()}
+            streams = {}
+            for p in stream_probes:
+                carry = data.pop(p.name)
+                self._stream_state[p.name] = carry
+                # host-offloaded snapshot: chunked runs keep device memory
+                # flat
+                streams[p.name] = {"carry": jax.tree.map(np.asarray, carry),
+                                   "meta": dict(p.meta)}
+            overflow = self._check_overflow()
+            return RunResult(
+                data=dict(data), t_model_ms=n_steps * self.sim_config.dt,
+                n_steps=n_steps, dt=self.sim_config.dt, wall_s=wall,
+                overflow=overflow, timers=timers, streams=streams,
+                _connectome=self.connectome)
 
     def _check_overflow(self) -> int:
         """Surface dropped spikes: warn on any new overflow since the last
         run, raise under ``SimConfig.strict_delivery``."""
-        overflow = self.backend.overflow(self._state)
+        with _span("repro.overflow"):
+            overflow = self.backend.overflow(self._state)
         if overflow > self._overflow_seen:
             msg = (f"spike delivery dropped {overflow - self._overflow_seen}"
                    f" spike(s) this run ({overflow} cumulative): the "
@@ -333,63 +349,69 @@ class Simulator:
         a single run's (warning, or ``DeliveryOverflowError`` under
         ``strict_delivery``).  The session's own state is untouched.
         """
-        seeds = self._trial_seeds(n_trials, seeds)
-        pr = self.probes if probes is None else probes_mod.resolve(probes)
-        step_probes, stream_probes = probes_mod.split_probes(pr)
-        keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
-        states = jax.vmap(self.backend.init)(keys)
-        t_pre = self.t_presim if presim_ms is None else float(presim_ms)
-        if t_pre > 0:
-            states, _, _ = self.backend.run_batch(states,
-                                                  self._steps(t_pre), ())
-            jax.block_until_ready(states)
-        n_steps = self._steps(t_ms)
-        # a warmed batch program re-compiling is a perf bug, not a warmup:
-        # arm a zero-budget recompile guard exactly when warm
-        guard = (RecompileGuard(0, caches=self.backend.caches(),
-                                what=f"run_batch({len(seeds)} trials x "
-                                     f"{n_steps} steps) after warmup")
-                 if self.backend.is_warm_batch(len(seeds), n_steps,
-                                               tuple(pr))
-                 else contextlib.nullcontext())
-        t0 = time.perf_counter()
-        with guard:
-            states, data, trial_walls = self.backend.run_batch(
-                states, n_steps, pr)
-        jax.block_until_ready((states, data))
-        wall = time.perf_counter() - t0
+        with _span("repro.run"):
+            seeds = self._trial_seeds(n_trials, seeds)
+            pr = self.probes if probes is None else probes_mod.resolve(probes)
+            step_probes, stream_probes = probes_mod.split_probes(pr)
+            keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
+            states = jax.vmap(self.backend.init)(keys)
+            t_pre = self.t_presim if presim_ms is None else float(presim_ms)
+            if t_pre > 0:
+                with _span("repro.presim"):
+                    states, _, _ = self.backend.run_batch(
+                        states, self._steps(t_pre), ())
+                    jax.block_until_ready(states)
+            n_steps = self._steps(t_ms)
+            # a warmed batch program re-compiling is a perf bug, not a warmup:
+            # arm a zero-budget recompile guard exactly when warm
+            guard = (RecompileGuard(0, caches=self.backend.caches(),
+                                    what=f"run_batch({len(seeds)} trials x "
+                                         f"{n_steps} steps) after warmup")
+                     if self.backend.is_warm_batch(len(seeds), n_steps,
+                                                   tuple(pr))
+                     else contextlib.nullcontext())
+            t0 = time.perf_counter()
+            with guard, _span("repro.dispatch"):
+                states, data, trial_walls = self.backend.run_batch(
+                    states, n_steps, pr)
+            with _span("repro.sync"):
+                jax.block_until_ready((states, data))
+            wall = time.perf_counter() - t0
+            with _span("repro.overflow"):
+                overflows = [self.backend.overflow(
+                    jax.tree.map(lambda x: x[i], states))
+                    for i in range(len(seeds))]
 
-        vmapped = trial_walls is None
-        trials = []
-        for i in range(len(seeds)):
-            st_i = jax.tree.map(lambda x: x[i], states)
-            data_i = {p.name: np.asarray(data[p.name][i])
-                      for p in step_probes}
-            streams_i = {}
-            for p in stream_probes:
-                carry = jax.tree.map(lambda x: np.asarray(x[i]),
-                                     data[p.name])
-                streams_i[p.name] = {"carry": carry, "meta": dict(p.meta)}
-            trials.append(RunResult(
-                data=data_i, t_model_ms=n_steps * self.sim_config.dt,
-                n_steps=n_steps, dt=self.sim_config.dt,
-                wall_s=(wall / len(seeds) if vmapped else trial_walls[i]),
-                overflow=self.backend.overflow(st_i),
-                streams=streams_i, _connectome=self.connectome))
-        overflow = sum(r.overflow for r in trials)
-        if overflow > 0:
-            msg = (f"spike delivery dropped {overflow} spike(s) across "
-                   f"{len(trials)} trial(s): the per-step spike_budget="
-                   f"{self.sim_config.spike_budget} of strategy "
-                   f"{self.sim_config.strategy!r} was exceeded — raise "
-                   f"spike_budget (or leave it None for the rate-derived "
-                   f"auto value)")
-            if self.sim_config.strict_delivery:
-                from repro.core.delivery import DeliveryOverflowError
-                raise DeliveryOverflowError(msg)
-            warnings.warn(msg, stacklevel=2)
-        return BatchResult(trials=trials, wall_s=wall, vmapped=vmapped,
-                           seeds=list(seeds))
+            vmapped = trial_walls is None
+            trials = []
+            for i in range(len(seeds)):
+                data_i = {p.name: np.asarray(data[p.name][i])
+                          for p in step_probes}
+                streams_i = {}
+                for p in stream_probes:
+                    carry = jax.tree.map(lambda x: np.asarray(x[i]),
+                                         data[p.name])
+                    streams_i[p.name] = {"carry": carry, "meta": dict(p.meta)}
+                trials.append(RunResult(
+                    data=data_i, t_model_ms=n_steps * self.sim_config.dt,
+                    n_steps=n_steps, dt=self.sim_config.dt,
+                    wall_s=(wall / len(seeds) if vmapped else trial_walls[i]),
+                    overflow=overflows[i],
+                    streams=streams_i, _connectome=self.connectome))
+            overflow = sum(r.overflow for r in trials)
+            if overflow > 0:
+                msg = (f"spike delivery dropped {overflow} spike(s) across "
+                       f"{len(trials)} trial(s): the per-step spike_budget="
+                       f"{self.sim_config.spike_budget} of strategy "
+                       f"{self.sim_config.strategy!r} was exceeded — raise "
+                       f"spike_budget (or leave it None for the rate-derived "
+                       f"auto value)")
+                if self.sim_config.strict_delivery:
+                    from repro.core.delivery import DeliveryOverflowError
+                    raise DeliveryOverflowError(msg)
+                warnings.warn(msg, stacklevel=2)
+            return BatchResult(trials=trials, wall_s=wall, vmapped=vmapped,
+                               seeds=list(seeds))
 
     def run_chunked(self, t_ms: float, chunk_ms: float, *,
                     presim_ms: Optional[float] = None,

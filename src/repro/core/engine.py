@@ -44,6 +44,7 @@ from repro.core.connectivity import Connectome
 from repro.core.kernel_policy import KernelPolicy
 from repro.core.neuron import NeuronParams, NeuronState, Propagators, lif_step
 from repro.core.params import InputParams
+from repro.perf.scopes import scope
 
 _DEFAULT_BG_RATE = 8.0
 
@@ -222,20 +223,35 @@ def _external_drive(state: SimState, net: Network, cfg: SimConfig,
     DC term.  Shared between the phase-split path and the fused one-kernel
     step so both see bitwise-identical drive values.
     """
-    i_dc = net.i_dc
-    if drive is None:
-        key, sub = jax.random.split(state.key)
-        lam = net.k_ext * (cfg.bg_rate * cfg.dt * 1e-3)
-        ext = jax.random.poisson(sub, lam, dtype=jnp.int32)
-        ext_ex = w_ext * ext.astype(dtype)
-    else:
-        keys = jax.random.split(state.key, drive.n_keys + 1)
-        key = keys[0]
-        I_ext, ext_in = drive(tuple(keys[1:]), state.t, state)
-        ext_ex = (None if ext_in is None
-                  else w_ext * ext_in.astype(dtype))
-        if I_ext is not None:
-            i_dc = i_dc + I_ext
+    with scope("drive"):
+        i_dc = net.i_dc
+        if drive is None:
+            key, sub = jax.random.split(state.key)
+            lam = net.k_ext * (cfg.bg_rate * cfg.dt * 1e-3)
+            ext = jax.random.poisson(sub, lam, dtype=jnp.int32)
+            ext_ex = w_ext * ext.astype(dtype)
+        else:
+            keys = jax.random.split(state.key, drive.n_keys + 1)
+            key = keys[0]
+            I_ext, ext_in = drive(tuple(keys[1:]), state.t, state)
+            ext_ex = (None if ext_in is None
+                      else w_ext * ext_in.astype(dtype))
+            if I_ext is not None:
+                i_dc = i_dc + I_ext
+    return key, ext_ex, i_dc
+
+
+def fused_drive(state: SimState, net: Network, cfg: SimConfig,
+                w_ext: float, n: int, drive: Optional[stim.Drive] = None):
+    """:func:`_external_drive` with its terms as the fused kernels take
+    them: ``ext_ex`` and ``i_dc`` both dense ``[n]`` in the ring dtype."""
+    dtype = state.ring.dtype
+    with scope("drive"):
+        key, ext_ex, i_dc = _external_drive(state, net, cfg, w_ext, dtype,
+                                            drive)
+        if ext_ex is None:
+            ext_ex = jnp.zeros((n,), dtype)
+        i_dc = jnp.broadcast_to(i_dc, (n,)).astype(dtype)
     return key, ext_ex, i_dc
 
 
@@ -251,31 +267,33 @@ def update_phase(state: SimState, net: Network, prop: Propagators,
     pre-registry hardcoded Poisson path (reads ``cfg.bg_rate``) — the
     bitwise reference the equivalence tests pin the default timeline to.
     """
-    D = state.ring.shape[0]
-    slot = state.t % D
-    arrivals = jax.lax.dynamic_index_in_dim(
-        state.ring, slot, axis=0, keepdims=False)       # [2, N+1]
-    in_ex = arrivals[0, :n]
-    in_in = arrivals[1, :n]
-
     key, ext_ex, i_dc = _external_drive(state, net, cfg, w_ext,
-                                        in_ex.dtype, drive)
-    if ext_ex is not None:
-        in_ex = in_ex + ext_ex
+                                        state.ring.dtype, drive)
+    with scope("lif_update"):
+        D = state.ring.shape[0]
+        slot = state.t % D
+        arrivals = jax.lax.dynamic_index_in_dim(
+            state.ring, slot, axis=0, keepdims=False)       # [2, N+1]
+        in_ex = arrivals[0, :n]
+        in_in = arrivals[1, :n]
+        if ext_ex is not None:
+            in_ex = in_ex + ext_ex
 
-    pol = kpol.policy_of(cfg)
-    use_kernel = cfg.use_lif_kernel if pol is None else pol.lif == "pallas"
-    if use_kernel:
-        from repro.kernels import ops as kops
-        neuron, spiked = kops.lif_update(
-            state.neuron, prop, in_ex, in_in, i_dc,
-            interpret=None if pol is None else pol.interpret)
-    else:
-        neuron, spiked = lif_step(state.neuron, prop, in_ex, in_in, i_dc)
+        pol = kpol.policy_of(cfg)
+        use_kernel = (cfg.use_lif_kernel if pol is None
+                      else pol.lif == "pallas")
+        if use_kernel:
+            from repro.kernels import ops as kops
+            neuron, spiked = kops.lif_update(
+                state.neuron, prop, in_ex, in_in, i_dc,
+                interpret=None if pol is None else pol.interpret)
+        else:
+            neuron, spiked = lif_step(state.neuron, prop, in_ex, in_in,
+                                      i_dc)
 
-    # consume the slot
-    ring = jax.lax.dynamic_update_index_in_dim(
-        state.ring, jnp.zeros_like(arrivals), slot, axis=0)
+        # consume the slot
+        ring = jax.lax.dynamic_update_index_in_dim(
+            state.ring, jnp.zeros_like(arrivals), slot, axis=0)
     return SimState(neuron, ring, state.t, key, state.overflow), spiked
 
 
@@ -297,15 +315,12 @@ def fused_update_phase(state: SimState, net: Network, prop: Propagators,
     """
     from repro.kernels import ops as kops
     pol = kpol.policy_of(cfg)
-    key, ext_ex, i_dc = _external_drive(state, net, cfg, w_ext,
-                                        state.ring.dtype, drive)
-    if ext_ex is None:
-        ext_ex = jnp.zeros((n,), state.ring.dtype)
-    i_dc = jnp.broadcast_to(i_dc, (n,)).astype(state.ring.dtype)
-    neuron, ring, spiked, ovf = kops.lif_deliver(
-        state.neuron, state.ring, state.t, spiked_prev, net.tables, prop,
-        ext_ex, i_dc, n_exc=n_exc, spike_budget=cfg.spike_budget,
-        interpret=None if pol is None else pol.interpret)
+    key, ext_ex, i_dc = fused_drive(state, net, cfg, w_ext, n, drive)
+    with scope("fused_step"):
+        neuron, ring, spiked, ovf = kops.lif_deliver(
+            state.neuron, state.ring, state.t, spiked_prev, net.tables,
+            prop, ext_ex, i_dc, n_exc=n_exc, spike_budget=cfg.spike_budget,
+            interpret=None if pol is None else pol.interpret)
     return SimState(neuron, ring, state.t + 1, key,
                     state.overflow + ovf), spiked
 
@@ -319,8 +334,9 @@ def deliver_phase(state: SimState, net: Network, cfg: SimConfig,
     ``deliver`` scatters into the ring and reports budget overflow.
     """
     strategy = dlv.get_strategy(cfg.strategy)
-    ring, ovf = strategy.deliver(state.ring, net.tables, spiked, state.t,
-                                 n_exc, cfg)
+    with scope("deliver"):
+        ring, ovf = strategy.deliver(state.ring, net.tables, spiked,
+                                     state.t, n_exc, cfg)
     return SimState(state.neuron, ring, state.t + 1, state.key,
                     state.overflow + ovf)
 

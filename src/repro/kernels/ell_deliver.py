@@ -220,5 +220,6 @@ def ell_deliver_pallas(ids: jnp.ndarray, targets: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit(ring_bytes, 0, n_lanes)),
         interpret=interpret,
+        name="ell_deliver",
     )(ids, meta, targets, weights, dbins)
     return ring_from_tiles(out, n_cols)

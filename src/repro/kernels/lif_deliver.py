@@ -182,10 +182,11 @@ def _kernel_plastic(ids_ref, meta_ref, tgt_hbm, w_hbm, db_hbm, pm_hbm,
         xposto_ref[...] = xpost_ref[...] * decay_m + spkf
 
 
-def _call(kernel, ids, t_prev, n_exc, tables, ring, vecs, n_vec_out,
+def _call(kernel, name, ids, t_prev, n_exc, tables, ring, vecs, n_vec_out,
           extra_out, aliases, *, d_bins, n_cols, block_k, interpret):
     """Shared ``pallas_call`` plumbing of the two fused kernels: HBM
-    tables and ring, tiled per-neuron vectors, one VMEM ring scratch.
+    tables and ring, tiled per-neuron vectors, one VMEM ring scratch;
+    ``name`` names the kernel on the device.
     ``vecs`` are the per-neuron input vectors; the last outputs are
     ``n_vec_out`` tiled vectors, f32 but for the fourth (refractory
     counter) and fifth (spikes, 0/1), which are int32."""
@@ -225,6 +226,7 @@ def _call(kernel, ids, t_prev, n_exc, tables, ring, vecs, n_vec_out,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(
             ring_bytes, len(vecs) + n_vec_out, n_lanes)),
         interpret=interpret,
+        name=name,
     )(ids, meta, *tables, ring_to_tiles(ring, n_lanes),
       *[to_tiles(x, n_lanes) for x in vecs])
     ring_out = ring_from_tiles(outs[0], n_cols)
@@ -254,8 +256,9 @@ def lif_deliver_pallas(ids, targets, weights, dbins, ring, V, I_ex, I_in,
               pad_table(weights, block_k, 0.0),
               pad_table(dbins, block_k, 1))
     ring_out, (Vo, iexo, iino, refo, spk) = _call(
-        functools.partial(_kernel_static, prop=prop), ids, t_prev, n_exc,
-        tables, ring, [V, I_ex, I_in, refrac, ext_ex, i_dc], 5, [],
+        functools.partial(_kernel_static, prop=prop), "lif_deliver_static",
+        ids, t_prev, n_exc, tables, ring,
+        [V, I_ex, I_in, refrac, ext_ex, i_dc], 5, [],
         # input 5 is the ring (indices count the 2 prefetch operands)
         {5: 0}, d_bins=d_bins, n_cols=n_cols, block_k=block_k,
         interpret=interpret)
@@ -294,7 +297,7 @@ def lif_deliver_plastic_pallas(ids, targets, weights, dbins, pmask, ring,
     ring_out, (w_out, Vo, iexo, iino, refo, spk, xpreo, xposto) = _call(
         functools.partial(_kernel_plastic, prop=prop, dep_coef=dep_coef,
                           decay_p=decay_p, decay_m=decay_m),
-        ids, t_prev, n_exc, tables, ring,
+        "lif_deliver_plastic", ids, t_prev, n_exc, tables, ring,
         [V, I_ex, I_in, refrac, ext_ex, i_dc, x_pre, x_post, spk_prev], 7,
         [w_shape],
         # ring -> ring', live weights -> depressed weights (input indices

@@ -80,5 +80,6 @@ def lif_update_pallas(V, I_ex, I_in, refrac, in_ex, in_in, i_dc,
         out_specs=(spec,) * 5,
         out_shape=out_shapes,
         interpret=interpret,
+        name="lif_update",
     )(*args)
     return tuple(o[:n] for o in outs)

@@ -30,9 +30,11 @@ _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s64": 8, "u64": 8,
                 "pred": 1, "f8e4m3fn": 1, "f8e5m2": 1, "c64": 8, "c128": 16}
 
 _COMP_RE = re.compile(r"^(?:ENTRY )?%?([\w.-]+) \(.*\) -> .* \{\s*$")
+# The result type runs to the first " opcode(": a TPU module's tuple
+# types hold parentheses of their own (``(s32[]{:T(128)}, ...)``).
 _INSTR_RE = re.compile(
-    r"^\s+(?:ROOT )?%?([\w.-]+) = ((?:\([^)]*\))|(?:[\w]+\[[^\]]*\]"
-    r"(?:\{[^}]*\})?))\s+([\w-]+)\(")
+    r"^\s+(?:ROOT )?%?([\w.-]+) = (\(.*?\)|[\w]+\[[^\]]*\]"
+    r"(?:\{[^}]*\})?)\s+([\w-]+)\(")
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _TRIP_RE = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
 _CALL_RE = re.compile(r"(?:calls|body|to_apply)=%?([\w.-]+)")

@@ -79,3 +79,18 @@ def test_live_module_flops_match_manual():
     expect = 7 * 2 * 32 * 64 * 64
     assert 0.9 * expect <= r["flops_per_device"] <= 1.2 * expect, \
         (r["flops_per_device"], expect)
+
+
+def test_parse_module_reads_tpu_tuple_types():
+    """A TPU module's tuple types carry layouts with parentheses."""
+    from repro.perf.hlo_analysis import parse_module
+    text = (
+        "ENTRY %main (a: f32[8]) -> f32[8] {\n"
+        "  %a = f32[8]{0:T(1024)} parameter(0)\n"
+        "  %w.1 = (s32[]{:T(128)}, f32[8]{0:T(1024)S(1)}) while(%t), "
+        "condition=%c, body=%b\n"
+        "  ROOT %o = f32[8]{0:T(1024)} get-tuple-element(%w.1), index=1\n"
+        "}\n")
+    ops = [(i.name, i.op) for i in parse_module(text)["main"]]
+    assert ops == [("a", "parameter"), ("w.1", "while"),
+                   ("o", "get-tuple-element")]

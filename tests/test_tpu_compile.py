@@ -4,7 +4,9 @@ Interpret mode on CPU checks what the kernels compute; it cannot check
 that Mosaic, the TPU kernel compiler, accepts them (tile-aligned blocks,
 no scalar stores into VMEM, the scoped-VMEM budget).  These tests
 AOT-compile each kernel ``auto`` can pick on TPU, with ``interpret=False``,
-for one chip of a described ``v5e:2x2`` topology — no chip is needed:
+for one chip of a described ``v5e:2x2`` topology — no chip is needed —
+and check that each reaches the device as one custom call under its own
+``name=``:
 
 * ``lif_update`` at the full-scale width, N = 77,169;
 * ``ell_deliver``, ``lif_deliver`` and ``lif_deliver_plastic`` at the
@@ -16,6 +18,7 @@ only one process at a time may load the TPU compiler's library.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -62,10 +65,13 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
-def _compile(fn, args, **static):
-    compiled = fn.lower(*args, **static).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    return compiled
+def _compile(fn, name, args, **static):
+    """Compile ``fn`` and check that its kernel reaches the device as one
+    custom call named ``name`` (the name the trace's op line shows)."""
+    text = fn.lower(*args, **static).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(rf"^\s*(ROOT )?%{name}(\.\d+)? = .* custom-call\(",
+                     text, re.M), f"no custom call named {name!r}"
 
 
 def _shapes(one_chip, n, k=K_FULL):
@@ -86,7 +92,7 @@ def _shapes(one_chip, n, k=K_FULL):
 
 def test_lif_update_compiles_at_full_scale(one_chip):
     s = _shapes(one_chip, N_FULL, k=128)
-    _compile(lif_update_pallas,
+    _compile(lif_update_pallas, "lif_update",
              (s["vf"], s["vf"], s["vf"], s["vi"], s["vf"], s["vf"],
               s["vf"]), prop=PROP)
 
@@ -95,7 +101,7 @@ def test_lif_update_compiles_at_full_scale(one_chip):
 def test_ell_deliver_compiles(one_chip, width):
     n = WIDTHS[width]
     s = _shapes(one_chip, n)
-    _compile(ell_deliver_pallas,
+    _compile(ell_deliver_pallas, "ell_deliver",
              (s["ids"], s["targets"], s["weights"], s["dbins"], s["t"]),
              d_bins=D_BINS, n_cols=n + 1, n_exc=n * 4 // 5)
 
@@ -104,7 +110,7 @@ def test_ell_deliver_compiles(one_chip, width):
 def test_lif_deliver_compiles(one_chip, width):
     n = WIDTHS[width]
     s = _shapes(one_chip, n)
-    _compile(lif_deliver_pallas,
+    _compile(lif_deliver_pallas, "lif_deliver_static",
              (s["ids"], s["targets"], s["weights"], s["dbins"], s["ring"],
               s["vf"], s["vf"], s["vf"], s["vi"], s["vf"], s["vf"],
               s["t"]),
@@ -115,7 +121,7 @@ def test_lif_deliver_compiles(one_chip, width):
 def test_lif_deliver_plastic_compiles(one_chip, width):
     n = WIDTHS[width]
     s = _shapes(one_chip, n)
-    _compile(lif_deliver_plastic_pallas,
+    _compile(lif_deliver_plastic_pallas, "lif_deliver_plastic",
              (s["ids"], s["targets"], s["weights"], s["dbins"], s["pmask"],
               s["ring"], s["vf"], s["vf"], s["vf"], s["vi"], s["vf"],
               s["vf"], s["vf"], s["vf"], s["vf"], s["t"]),
