@@ -28,8 +28,10 @@ registry instead of hardcoding branches:
   (``repro.kernels.ell_deliver``): the step's spike ids are scalar-
   prefetched, their padded ELL rows are gathered tile-by-tile straight from
   HBM, and the (target, weight, slot) triples scatter-add into the ring
-  on-chip.  O(S*K) work and O(N*K) memory — the only layout that reaches
-  the paper's full scale (~0.3 billion explicit synapses).  Off-TPU the
+  on-chip, walking only the row tiles that hold real synapses.  Work
+  follows the delivered rows' real lengths, memory is O(N*K) — the only
+  layout that reaches the paper's full scale (~0.3 billion explicit
+  synapses).  Off-TPU the
   strategy runs the same math through the pure-jnp gather/scatter path
   unless the resolved ``SimConfig.kernels`` policy
   (``KernelPolicy(deliver='pallas')``) forces the (interpret-mode) kernel.
@@ -72,10 +74,14 @@ class DeliveryOverflowError(RuntimeError):
 
 
 class EventTables(NamedTuple):
-    """Padded ELL out-adjacency, plus one sentinel row at index N."""
+    """Padded ELL out-adjacency, plus one sentinel row at index N.
+
+    ``row_len`` is each row's real length (:func:`row_lengths`): the ELL
+    kernels walk only the row tiles below it."""
     targets: jnp.ndarray   # [N+1, K] int32 in [0, N]; N == dump
     weights: jnp.ndarray   # [N+1, K] float32
     dbins: jnp.ndarray     # [N+1, K] int32 >= 1
+    row_len: jnp.ndarray   # [N+1] int32; 0 on the sentinel (and pad) rows
 
 
 class DenseTables(NamedTuple):
@@ -94,16 +100,28 @@ class DenseTables(NamedTuple):
     W_in: Optional[jnp.ndarray] = None     # [N - n_exc, D * N_post]
 
 
+@jax.jit
+def row_lengths(targets, sentinel) -> jnp.ndarray:
+    """Real length of each ELL row ``[R]`` int32: one past its last entry
+    that does not point at the dump column ``sentinel``, 0 for a row of
+    sentinel entries only.  Rows are front-packed
+    (``connectivity.build_connectome``), so this is the out-degree."""
+    col = jnp.arange(1, targets.shape[1] + 1, dtype=jnp.int32)
+    return jnp.max(jnp.where(targets != sentinel, col, 0), axis=1)
+
+
 def make_event_tables(targets, weights, dbins) -> EventTables:
     """Append the sentinel source row (all entries point at the dump slot)."""
     n, k = targets.shape
     pad_t = jnp.full((1, k), n, dtype=targets.dtype)
     pad_w = jnp.zeros((1, k), dtype=weights.dtype)
     pad_d = jnp.ones((1, k), dtype=dbins.dtype)
+    targets = jnp.concatenate([targets, pad_t], axis=0)
     return EventTables(
-        targets=jnp.concatenate([targets, pad_t], axis=0),
+        targets=targets,
         weights=jnp.concatenate([weights, pad_w], axis=0),
         dbins=jnp.concatenate([dbins, pad_d], axis=0),
+        row_len=row_lengths(targets, n),
     )
 
 
@@ -411,10 +429,12 @@ class EllDelivery(DeliveryStrategy):
                     -(-k // self.block_k) * self.block_k)
         rows = -(-(n + 1) // self.row_tile) * self.row_tile
         pad = ((0, rows - n), (0, k_pad - k))
+        targets = jnp.asarray(np.pad(c.targets, pad, constant_values=n))
         return EventTables(
-            targets=jnp.asarray(np.pad(c.targets, pad, constant_values=n)),
+            targets=targets,
             weights=jnp.asarray(np.pad(c.weights, pad)),
-            dbins=jnp.asarray(np.pad(c.dbins, pad, constant_values=1)))
+            dbins=jnp.asarray(np.pad(c.dbins, pad, constant_values=1)),
+            row_len=row_lengths(targets, n))
 
     def memory_bytes(self, c) -> int:
         n, k = c.targets.shape

@@ -6,11 +6,12 @@ out-adjacency ``[N+1, K]`` followed by a scatter-add of the ``S x K``
 of that pattern materialises the ``[S, K]`` gathered rows in HBM and runs
 the scatter as a second pass; this kernel fuses both:
 
-* the step's spike ids are **scalar-prefetched** (SMEM), and the three ELL
-  tables stay in HBM (``memory_space=ANY``).  Each grid step DMAs the
-  ``(8, block_k)`` tile that holds row ``ids[s]`` into SMEM scratch — the
-  TPU DMA engine moves whole ``(8, 128)`` tiles, so a lone row cannot be a
-  block — and reads that row's triples as scalars,
+* the step's spike ids and their rows' lengths are **scalar-prefetched**
+  (SMEM), and the three ELL tables stay in HBM (``memory_space=ANY``).
+  Each grid step that has work DMAs the ``(8, block_k)`` tile that holds
+  row ``ids[s]`` into SMEM scratch — the TPU DMA engine moves whole
+  ``(8, 128)`` tiles, so a lone row cannot be a block — and reads that
+  row's triples as scalars,
 * each triple is **scatter-added on-chip** into a VMEM-resident ring laid
   out as ``[2D, N_lanes/1024, 8, 128]`` (rows ``slot*2 + channel``): the
   ``(8, 128)`` tile holding the target gets a masked add at the target's
@@ -19,15 +20,22 @@ the scatter as a second pass; this kernel fuses both:
   dump column with weight 0.
 
 The ring update accumulates across the whole grid in one VMEM scratch
-buffer and is DMA'd to HBM once, at the last grid step.  Work is O(S*K),
-memory O(N*K).  The single resident ring caps the kernel at
+buffer and is DMA'd to HBM once, at the last grid step.  Memory is
+O(N*K).  The single resident ring caps the kernel at
 ``kernel_policy.FUSED_MAX_RING_BYTES`` of VMEM; past that ``auto`` keeps
 the XLA gather/scatter.
 
 Grid: ``(S, K/block_k)`` — spikes outer, row tiles inner, so the scatter
 order (s-major, k-minor) matches the XLA scatter of ``deliver_event`` and
-results agree bitwise.  The per-synapse loop is scalar; it is the
-correctness baseline, not a tuned kernel.
+results agree bitwise.  Work follows the delivered rows' real tiles, not
+the grid: the real length ``len[s]`` of row ``ids[s]``
+(``EventTables.row_len``, 0 for the sentinel row) is scalar-prefetched
+too, and grid step ``(s, kb)`` fetches and walks its tile only if the
+tile holds a real synapse, ``kb * block_k < len[s]`` (:func:`when_live`).
+Sentinel rows that pad ``ids`` up to the budget and the padded tail of
+each row cost a grid step each and nothing more; a skipped entry would
+only have added weight 0 into the dump column.  :func:`walked_tiles`
+counts the tiles walked.  The per-synapse loop is scalar.
 """
 from __future__ import annotations
 
@@ -137,27 +145,46 @@ def gather_lane(vec_ref, tg):
     return jnp.max(jnp.where(_hit(tg), tile, -jnp.inf))
 
 
-def deliver_row_tile(s, kb, ids_ref, meta_ref, tables, smem, ring, sems,
-                     *, d_bins: int, block_k: int):
+def walked_tiles(lens: jnp.ndarray, block_k: int) -> jnp.ndarray:
+    """Row tiles the ELL kernels walk for delivered rows of real lengths
+    ``lens``: ``ceil(len / block_k)`` each, the tiles :func:`when_live`
+    admits, summed (int32)."""
+    return jnp.sum(-(-lens // block_k), dtype=jnp.int32)
+
+
+def when_live(s, kb, lens_ref, *, block_k: int):
+    """``pl.when`` over the grid steps with work: tile ``kb`` of delivered
+    spike ``s``'s row holds a real synapse, ``kb * block_k < len[s]``.
+    Every real entry lies below its row's length, so this skips only the
+    sentinel rows (length 0) and each row's padded tail."""
+    return pl.when(kb * block_k < lens_ref[s])
+
+
+def deliver_row_tile(s, kb, ids_ref, meta_ref, lens_ref, tables, smem, ring,
+                     sems, *, d_bins: int, block_k: int):
     """Scatter tile ``kb`` of spike ``s``'s ELL row into the resident ring,
-    at ring phase ``meta[0]`` (``meta = [t, n_exc]``)."""
-    t = meta_ref[0]
-    sid = ids_ref[s]
-    r = fetch_row_tile(sid, kb, tables, smem, sems, block_k=block_k)
-    # Dale's law: the source row sets the sign channel.  The sentinel row
-    # (sid == N >= n_exc) carries weight 0 into the dump column.
-    ch = jnp.where(sid >= meta_ref[1], 1, 0).astype(jnp.int32)
-    tgt_s, w_s, db_s = smem
+    at ring phase ``meta[0]`` (``meta = [t, n_exc]``), if the tile holds a
+    real synapse."""
 
-    def body(j, _):
-        slot = jax.lax.rem(t + db_s[r, j], d_bins)
-        scatter_add(ring, slot * 2 + ch, tgt_s[r, j], w_s[r, j])
-        return 0
+    @when_live(s, kb, lens_ref, block_k=block_k)
+    def _walk():
+        t = meta_ref[0]
+        sid = ids_ref[s]
+        r = fetch_row_tile(sid, kb, tables, smem, sems, block_k=block_k)
+        # Dale's law: the source row sets the sign channel.  Padded
+        # entries carry weight 0 into the dump column.
+        ch = jnp.where(sid >= meta_ref[1], 1, 0).astype(jnp.int32)
+        tgt_s, w_s, db_s = smem
 
-    jax.lax.fori_loop(0, block_k, body, 0)
+        def body(j, _):
+            slot = jax.lax.rem(t + db_s[r, j], d_bins)
+            scatter_add(ring, slot * 2 + ch, tgt_s[r, j], w_s[r, j])
+            return 0
+
+        jax.lax.fori_loop(0, block_k, body, 0)
 
 
-def _kernel(ids_ref, meta_ref, tgt_hbm, w_hbm, db_hbm, out_hbm,
+def _kernel(ids_ref, meta_ref, lens_ref, tgt_hbm, w_hbm, db_hbm, out_hbm,
             tgt_s, w_s, db_s, acc, sems, *, d_bins: int, block_k: int):
     s = pl.program_id(0)
     kb = pl.program_id(1)
@@ -166,9 +193,9 @@ def _kernel(ids_ref, meta_ref, tgt_hbm, w_hbm, db_hbm, out_hbm,
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    deliver_row_tile(s, kb, ids_ref, meta_ref, (tgt_hbm, w_hbm, db_hbm),
-                     (tgt_s, w_s, db_s), acc, sems, d_bins=d_bins,
-                     block_k=block_k)
+    deliver_row_tile(s, kb, ids_ref, meta_ref, lens_ref,
+                     (tgt_hbm, w_hbm, db_hbm), (tgt_s, w_s, db_s), acc, sems,
+                     d_bins=d_bins, block_k=block_k)
 
     @pl.when((s == pl.num_programs(0) - 1) & (kb == pl.num_programs(1) - 1))
     def _flush():
@@ -177,16 +204,18 @@ def _kernel(ids_ref, meta_ref, tgt_hbm, w_hbm, db_hbm, out_hbm,
 
 @functools.partial(jax.jit, static_argnames=("d_bins", "n_cols", "block_k",
                                              "n_exc", "interpret"))
-def ell_deliver_pallas(ids: jnp.ndarray, targets: jnp.ndarray,
-                       weights: jnp.ndarray, dbins: jnp.ndarray,
-                       t: jnp.ndarray, *, d_bins: int, n_cols: int,
-                       n_exc: int, block_k: int = 128,
+def ell_deliver_pallas(ids: jnp.ndarray, lens: jnp.ndarray,
+                       targets: jnp.ndarray, weights: jnp.ndarray,
+                       dbins: jnp.ndarray, t: jnp.ndarray, *, d_bins: int,
+                       n_cols: int, n_exc: int, block_k: int = 128,
                        interpret: bool = False) -> jnp.ndarray:
     """Ring update from S spike ids through ELL tables.
 
-    ``ids``[S] int32 in [0, N] (N = sentinel row), tables ``[N+1, K]``
-    (rows past N, if any, are sentinel rows).
-    Returns ``upd[d_bins, 2, n_cols]`` f32 to be added onto the ring.
+    ``ids``[S] int32 in [0, N] (N = sentinel row), ``lens``[S] int32 the
+    real lengths of their rows (``EventTables.row_len[ids]``; 0 for the
+    sentinel), tables ``[N+1, K]`` (rows past N, if any, are sentinel
+    rows).  Returns ``upd[d_bins, 2, n_cols]`` f32 to be added onto the
+    ring.
     """
     s_budget = ids.shape[0]
     n_sent = n_cols - 1
@@ -199,7 +228,7 @@ def ell_deliver_pallas(ids: jnp.ndarray, targets: jnp.ndarray,
                       jnp.full((), n_exc, jnp.int32)])
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(s_budget, targets.shape[1] // block_k),
         in_specs=[hbm, hbm, hbm],
         out_specs=hbm,
@@ -221,5 +250,5 @@ def ell_deliver_pallas(ids: jnp.ndarray, targets: jnp.ndarray,
             vmem_limit_bytes=vmem_limit(ring_bytes, 0, n_lanes)),
         interpret=interpret,
         name="ell_deliver",
-    )(ids, meta, targets, weights, dbins)
+    )(ids, meta, lens, targets, weights, dbins)
     return ring_from_tiles(out, n_cols)

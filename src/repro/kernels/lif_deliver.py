@@ -12,7 +12,11 @@ scalar-prefetched spike ids resident on-chip across one step:
 * grid ``(S+1, K/block_k)`` — the first ``S`` rows replay the sparse-ELL
   delivery of the *previous* step's spike ids (row tiles DMA'd from HBM
   into SMEM, masked-tile scatter into the resident ring, s-major / k-minor
-  order, exactly :mod:`repro.kernels.ell_deliver`);
+  order, exactly :mod:`repro.kernels.ell_deliver`).  Work follows the
+  delivered rows' real tiles: grid step ``(s, kb)`` with ``s < S`` walks
+  its tile only if ``kb * block_k < len[s]``, the real length of row
+  ``ids[s]`` (scalar-prefetched; 0 for the sentinel rows that pad ``ids``
+  to the budget), and otherwise starts no DMA and runs no loop;
 * the final grid row (``s == S, kb == 0``) runs the whole-network LIF
   update of :mod:`repro.kernels.lif_update` against the just-scattered
   ring, tile by tile: it reads the current slot's arrival rows, integrates
@@ -30,11 +34,13 @@ epilogue after the scan.
 ``lif_deliver_plastic`` additionally folds the pair-STDP depression and
 trace decay into the same pass: each fetched ELL weight tile is written
 back depressed (``w -= lr*A_minus*w_ref*x_post[target]`` on plastic
-synapses) while it is on-chip for the ring scatter, and the pre/post
-traces decay+bump in the LIF phase.  The potentiation scatter (indexed by
-the transposed in-adjacency, a different access pattern) and the weight
-clip stay in XLA — ``repro.core.plasticity.stdp_pot_clip`` applies them
-to the kernel's output in ``stdp_step``'s op order.
+synapses) while it is on-chip for the ring scatter (a tile that is not
+walked holds no plastic synapse of the row and is not written back),
+and the pre/post traces decay+bump in the LIF phase.  The potentiation
+scatter (indexed by the transposed in-adjacency, a different access
+pattern) and the weight clip stay in XLA —
+``repro.core.plasticity.stdp_pot_clip`` applies them to the kernel's
+output in ``stdp_step``'s op order.
 
 Everything is f32 and the full ring must fit in VMEM
 (``kernel_policy.FUSED_MAX_RING_BYTES``); ``kernel_policy.resolve`` gates
@@ -55,7 +61,7 @@ from repro.kernels.ell_deliver import (LANE, SUB, TILE, deliver_row_tile,
                                        pad_table, ring_from_tiles,
                                        ring_lanes, ring_to_tiles,
                                        scatter_add, store_row_tile, to_tiles,
-                                       vmem_limit)
+                                       vmem_limit, when_live)
 
 
 def _lif_math(V, I_ex, I_in, refrac, in_ex, in_in, i_dc,
@@ -101,8 +107,8 @@ def _lif_phase(meta_ref, ins, ring, outs, ring_out, sem, *, d_bins: int,
     dma(ring, ring_out, sem)
 
 
-def _kernel_static(ids_ref, meta_ref, tgt_hbm, w_hbm, db_hbm, ring_in,
-                   V_ref, iex_ref, iin_ref, ref_ref, ext_ref, idc_ref,
+def _kernel_static(ids_ref, meta_ref, lens_ref, tgt_hbm, w_hbm, db_hbm,
+                   ring_in, V_ref, iex_ref, iin_ref, ref_ref, ext_ref, idc_ref,
                    ring_out, Vo_ref, iexo_ref, iino_ref, refo_ref, spk_ref,
                    tgt_s, w_s, db_s, ring, sems,
                    *, d_bins: int, block_k: int, s_budget: int,
@@ -116,9 +122,9 @@ def _kernel_static(ids_ref, meta_ref, tgt_hbm, w_hbm, db_hbm, ring_in,
 
     @pl.when(s < s_budget)
     def _deliver():
-        deliver_row_tile(s, kb, ids_ref, meta_ref, (tgt_hbm, w_hbm, db_hbm),
-                         (tgt_s, w_s, db_s), ring, sems, d_bins=d_bins,
-                         block_k=block_k)
+        deliver_row_tile(s, kb, ids_ref, meta_ref, lens_ref,
+                         (tgt_hbm, w_hbm, db_hbm), (tgt_s, w_s, db_s), ring,
+                         sems, d_bins=d_bins, block_k=block_k)
 
     @pl.when((s == s_budget) & (kb == 0))
     def _update():
@@ -129,9 +135,9 @@ def _kernel_static(ids_ref, meta_ref, tgt_hbm, w_hbm, db_hbm, ring_in,
                    prop=prop)
 
 
-def _kernel_plastic(ids_ref, meta_ref, tgt_hbm, w_hbm, db_hbm, pm_hbm,
-                    ring_in, V_ref, iex_ref, iin_ref, ref_ref, ext_ref,
-                    idc_ref, xpre_ref, xpost_ref, spkprev_ref,
+def _kernel_plastic(ids_ref, meta_ref, lens_ref, tgt_hbm, w_hbm, db_hbm,
+                    pm_hbm, ring_in, V_ref, iex_ref, iin_ref, ref_ref,
+                    ext_ref, idc_ref, xpre_ref, xpost_ref, spkprev_ref,
                     ring_out, w_out, Vo_ref, iexo_ref, iino_ref, refo_ref,
                     spk_ref, xpreo_ref, xposto_ref,
                     tgt_s, w_s, db_s, pm_s, ring, sems,
@@ -147,28 +153,32 @@ def _kernel_plastic(ids_ref, meta_ref, tgt_hbm, w_hbm, db_hbm, pm_hbm,
 
     @pl.when(s < s_budget)
     def _deliver():
-        t_prev = meta_ref[0]
-        sid = ids_ref[s]
-        ch = jnp.where(sid >= meta_ref[1], 1, 0).astype(jnp.int32)
-        # weights come from the aliased output: an earlier spike in the
-        # same 8-row tile has already written its depressed row there
-        r = fetch_row_tile(sid, kb, (tgt_hbm, w_out, db_hbm, pm_hbm),
-                           (tgt_s, w_s, db_s, pm_s), sems, block_k=block_k)
+        @when_live(s, kb, lens_ref, block_k=block_k)
+        def _walk():
+            t_prev = meta_ref[0]
+            sid = ids_ref[s]
+            ch = jnp.where(sid >= meta_ref[1], 1, 0).astype(jnp.int32)
+            # weights come from the aliased output: an earlier spike in
+            # the same 8-row tile has already written its depressed row
+            r = fetch_row_tile(sid, kb, (tgt_hbm, w_out, db_hbm, pm_hbm),
+                               (tgt_s, w_s, db_s, pm_s), sems,
+                               block_k=block_k)
 
-        def body(j, _):
-            tg = tgt_s[r, j]
-            w = w_s[r, j]
-            slot = jax.lax.rem(t_prev + db_s[r, j], d_bins)
-            scatter_add(ring, slot * 2 + ch, tg, w)
-            # pair-STDP depression on the fetched tile while it's
-            # on-chip: same single-rounded coefficient as stdp_step
-            xp = gather_lane(xpost_ref, tg)
-            dw = jnp.where(pm_s[r, j] != 0, -(dep_coef * xp), 0.0)
-            w_s[r, j] = w + dw
-            return 0
+            def body(j, _):
+                tg = tgt_s[r, j]
+                w = w_s[r, j]
+                slot = jax.lax.rem(t_prev + db_s[r, j], d_bins)
+                scatter_add(ring, slot * 2 + ch, tg, w)
+                # pair-STDP depression on the fetched tile while it's
+                # on-chip: same single-rounded coefficient as stdp_step
+                xp = gather_lane(xpost_ref, tg)
+                dw = jnp.where(pm_s[r, j] != 0, -(dep_coef * xp), 0.0)
+                w_s[r, j] = w + dw
+                return 0
 
-        jax.lax.fori_loop(0, block_k, body, 0)
-        store_row_tile(sid, kb, w_s, w_out, sems.at[0], block_k=block_k)
+            jax.lax.fori_loop(0, block_k, body, 0)
+            store_row_tile(sid, kb, w_s, w_out, sems.at[0],
+                           block_k=block_k)
 
     @pl.when((s == s_budget) & (kb == 0))
     def _update():
@@ -182,9 +192,11 @@ def _kernel_plastic(ids_ref, meta_ref, tgt_hbm, w_hbm, db_hbm, pm_hbm,
         xposto_ref[...] = xpost_ref[...] * decay_m + spkf
 
 
-def _call(kernel, name, ids, t_prev, n_exc, tables, ring, vecs, n_vec_out,
-          extra_out, aliases, *, d_bins, n_cols, block_k, interpret):
-    """Shared ``pallas_call`` plumbing of the two fused kernels: HBM
+def _call(kernel, name, ids, lens, t_prev, n_exc, tables, ring, vecs,
+          n_vec_out, extra_out, aliases, *, d_bins, n_cols, block_k,
+          interpret):
+    """Shared ``pallas_call`` plumbing of the two fused kernels: three
+    scalar-prefetch operands (``ids``, ``[t_prev, n_exc]``, ``lens``), HBM
     tables and ring, tiled per-neuron vectors, one VMEM ring scratch;
     ``name`` names the kernel on the device.
     ``vecs`` are the per-neuron input vectors; the last outputs are
@@ -199,13 +211,13 @@ def _call(kernel, name, ids, t_prev, n_exc, tables, ring, vecs, n_vec_out,
                       jnp.full((), n_exc, jnp.int32)])
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     vec = pl.BlockSpec((n_tiles, SUB, LANE),
-                       lambda s, kb, ids, meta: (0, 0, 0))
+                       lambda s, kb, ids, meta, lens: (0, 0, 0))
     tile = jax.ShapeDtypeStruct((n_tiles, SUB, LANE), jnp.float32)
     vec_out = [tile] * n_vec_out
     vec_out[3] = jax.ShapeDtypeStruct(tile.shape, jnp.int32)      # refrac
     vec_out[4] = jax.ShapeDtypeStruct(tile.shape, jnp.int32)      # spiked
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(s_budget + 1, tables[0].shape[1] // block_k),
         in_specs=[hbm] * (len(tables) + 1) + [vec] * len(vecs),
         out_specs=[hbm] * (1 + len(extra_out)) + [vec] * n_vec_out,
@@ -227,7 +239,7 @@ def _call(kernel, name, ids, t_prev, n_exc, tables, ring, vecs, n_vec_out,
             ring_bytes, len(vecs) + n_vec_out, n_lanes)),
         interpret=interpret,
         name=name,
-    )(ids, meta, *tables, ring_to_tiles(ring, n_lanes),
+    )(ids, meta, lens, *tables, ring_to_tiles(ring, n_lanes),
       *[to_tiles(x, n_lanes) for x in vecs])
     ring_out = ring_from_tiles(outs[0], n_cols)
     return ring_out, outs[1:]
@@ -239,14 +251,15 @@ def _vec(x, n):
 
 @functools.partial(jax.jit, static_argnames=(
     "d_bins", "n_cols", "n", "n_exc", "prop", "block_k", "interpret"))
-def lif_deliver_pallas(ids, targets, weights, dbins, ring, V, I_ex, I_in,
-                       refrac, ext_ex, i_dc, t_prev, *, d_bins: int,
+def lif_deliver_pallas(ids, lens, targets, weights, dbins, ring, V, I_ex,
+                       I_in, refrac, ext_ex, i_dc, t_prev, *, d_bins: int,
                        n_cols: int, n: int, n_exc: int, prop: Propagators,
                        block_k: int = 128, interpret: bool = False):
     """One fused step: deliver ``ids`` at ring phase ``t_prev``, then
     integrate step ``t_prev + 1``.
 
-    ``ids``[S] int32 in [0, N] (N = sentinel), ELL tables ``[N+1, K]``
+    ``ids``[S] int32 in [0, N] (N = sentinel), ``lens``[S] int32 the real
+    lengths of their rows (0 for the sentinel), ELL tables ``[N+1, K]``
     (rows past N, if any, are sentinel rows),
     ``ring``[D, 2, n_cols] f32, state vectors [n] (n = n_cols - 1),
     ``ext_ex``/``i_dc`` the pre-scaled external drive.  Returns
@@ -257,10 +270,10 @@ def lif_deliver_pallas(ids, targets, weights, dbins, ring, V, I_ex, I_in,
               pad_table(dbins, block_k, 1))
     ring_out, (Vo, iexo, iino, refo, spk) = _call(
         functools.partial(_kernel_static, prop=prop), "lif_deliver_static",
-        ids, t_prev, n_exc, tables, ring,
+        ids, lens, t_prev, n_exc, tables, ring,
         [V, I_ex, I_in, refrac, ext_ex, i_dc], 5, [],
-        # input 5 is the ring (indices count the 2 prefetch operands)
-        {5: 0}, d_bins=d_bins, n_cols=n_cols, block_k=block_k,
+        # input 6 is the ring (indices count the 3 prefetch operands)
+        {6: 0}, d_bins=d_bins, n_cols=n_cols, block_k=block_k,
         interpret=interpret)
     return (ring_out, _vec(Vo, n), _vec(iexo, n), _vec(iino, n),
             _vec(refo, n), _vec(spk, n) != 0)
@@ -269,7 +282,8 @@ def lif_deliver_pallas(ids, targets, weights, dbins, ring, V, I_ex, I_in,
 @functools.partial(jax.jit, static_argnames=(
     "d_bins", "n_cols", "n", "n_exc", "prop", "block_k", "interpret",
     "dep_coef", "decay_p", "decay_m"))
-def lif_deliver_plastic_pallas(ids, targets, weights, dbins, pmask, ring,
+def lif_deliver_plastic_pallas(ids, lens, targets, weights, dbins, pmask,
+                               ring,
                                V, I_ex, I_in, refrac, ext_ex, i_dc,
                                x_pre, x_post, spk_prev, t_prev, *,
                                d_bins: int, n_cols: int, n: int,
@@ -280,7 +294,8 @@ def lif_deliver_plastic_pallas(ids, targets, weights, dbins, pmask, ring,
     """Plastic fused step: static step + in-tile pair-STDP depression and
     on-chip trace decay.
 
-    ``weights`` must be the *live* plastic weight table (ELL-padded view
+    ``ids`` and ``lens`` as for :func:`lif_deliver_pallas`.  ``weights``
+    must be the *live* plastic weight table (ELL-padded view
     of the flat plastic weights) and ``pmask`` its plastic-synapse mask
     (bool or int32), both shaped like ``targets``; ``spk_prev`` is
     ``spiked_prev`` as f32 (the trace bump of the step whose spikes are
@@ -297,12 +312,12 @@ def lif_deliver_plastic_pallas(ids, targets, weights, dbins, pmask, ring,
     ring_out, (w_out, Vo, iexo, iino, refo, spk, xpreo, xposto) = _call(
         functools.partial(_kernel_plastic, prop=prop, dep_coef=dep_coef,
                           decay_p=decay_p, decay_m=decay_m),
-        "lif_deliver_plastic", ids, t_prev, n_exc, tables, ring,
+        "lif_deliver_plastic", ids, lens, t_prev, n_exc, tables, ring,
         [V, I_ex, I_in, refrac, ext_ex, i_dc, x_pre, x_post, spk_prev], 7,
         [w_shape],
         # ring -> ring', live weights -> depressed weights (input indices
-        # count the 2 prefetch operands)
-        {6: 0, 3: 1}, d_bins=d_bins, n_cols=n_cols, block_k=block_k,
+        # count the 3 prefetch operands)
+        {7: 0, 4: 1}, d_bins=d_bins, n_cols=n_cols, block_k=block_k,
         interpret=interpret)
     rows, k = weights.shape
     return (ring_out, w_out[:rows, :k], _vec(Vo, n), _vec(iexo, n),

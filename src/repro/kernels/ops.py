@@ -54,9 +54,9 @@ def ell_deliver(ring: jnp.ndarray, tables, spiked: jnp.ndarray,
     n_spikes = jnp.sum(spiked, dtype=jnp.int32)
     (ids,) = jnp.nonzero(spiked, size=spike_budget, fill_value=n)
     upd = ell_deliver_pallas(
-        ids.astype(jnp.int32), tables.targets, tables.weights, tables.dbins,
-        t, d_bins=D, n_cols=n_cols, n_exc=n_exc, block_k=block_k,
-        interpret=interpret)
+        ids.astype(jnp.int32), tables.row_len[ids], tables.targets,
+        tables.weights, tables.dbins, t, d_bins=D, n_cols=n_cols,
+        n_exc=n_exc, block_k=block_k, interpret=interpret)
     overflow = jnp.maximum(n_spikes - spike_budget, 0)
     return ring + upd.astype(ring.dtype), overflow
 
@@ -81,10 +81,11 @@ def lif_deliver(state: NeuronState, ring: jnp.ndarray, t: jnp.ndarray,
     (ids,) = jnp.nonzero(spiked_prev, size=spike_budget, fill_value=n)
     t_prev = jnp.asarray(t, jnp.int32) - 1
     ring_out, V, I_ex, I_in, refrac, spiked = lif_deliver_pallas(
-        ids.astype(jnp.int32), tables.targets, tables.weights, tables.dbins,
-        ring, state.V, state.I_ex, state.I_in, state.refrac, ext_ex, i_dc,
-        t_prev, d_bins=D, n_cols=n_cols, n=n, n_exc=n_exc, prop=prop,
-        block_k=block_k, interpret=interpret)
+        ids.astype(jnp.int32), tables.row_len[ids], tables.targets,
+        tables.weights, tables.dbins, ring, state.V, state.I_ex,
+        state.I_in, state.refrac, ext_ex, i_dc, t_prev, d_bins=D,
+        n_cols=n_cols, n=n, n_exc=n_exc, prop=prop, block_k=block_k,
+        interpret=interpret)
     overflow = jnp.maximum(n_spikes - spike_budget, 0)
     return (NeuronState(V, I_ex, I_in, refrac),
             ring_out.astype(ring.dtype).reshape(ring.shape),
@@ -120,9 +121,9 @@ def lif_deliver_plastic(state: NeuronState, ring: jnp.ndarray,
     spk_prev = spiked_prev.astype(jnp.float32)
     (ring_out, w_out, V, I_ex, I_in, refrac, spiked, xpre_o,
      xpost_o) = lif_deliver_plastic_pallas(
-        ids, tables.targets, w_live, tables.dbins, pmask, ring,
-        state.V, state.I_ex, state.I_in, state.refrac, ext_ex, i_dc,
-        x_pre, x_post, spk_prev, t_prev, d_bins=D, n_cols=n_cols, n=n,
+        ids, tables.row_len[ids], tables.targets, w_live, tables.dbins,
+        pmask, ring, state.V, state.I_ex, state.I_in, state.refrac, ext_ex,
+        i_dc, x_pre, x_post, spk_prev, t_prev, d_bins=D, n_cols=n_cols, n=n,
         n_exc=n_exc, prop=prop, dep_coef=dep_coef, decay_p=decay_p,
         decay_m=decay_m, block_k=block_k, interpret=interpret)
     overflow = jnp.maximum(n_spikes - spike_budget, 0)

@@ -246,6 +246,49 @@ def test_ell_table_rows_are_lane_padded(tiny_c):
     np.testing.assert_array_equal(np.asarray(tables.weights[n:]), 0.0)
 
 
+def test_row_len_is_the_out_degree_and_survives_live_tables(tiny_c):
+    """``row_len`` is each row's real length: the connectome's out-degree,
+    0 on the sentinel row N and every pad row after it; swapping in live
+    weights keeps it."""
+    c = tiny_c
+    for name in ("ell", "event"):
+        strat = dlv.get_strategy(name)
+        tables = strat.prepare(c, SimConfig(strategy=name))
+        row_len = np.asarray(tables.row_len)
+        assert row_len.dtype == np.int32
+        assert row_len.shape == (tables.targets.shape[0],)
+        np.testing.assert_array_equal(row_len[:c.n_total], c.out_degree)
+        np.testing.assert_array_equal(row_len[c.n_total:], 0)
+        live = strat.live_tables(
+            tables, jnp.zeros((c.n_total + 1, c.targets.shape[1])))
+        assert live.row_len is tables.row_len
+
+
+def test_row_lengths_reach_the_last_real_entry():
+    """A row's length runs to its last real entry, so a row with a
+    sentinel hole is walked in full; an all-sentinel row has length 0."""
+    n = 5
+    targets = np.full((4, 300), n, np.int32)
+    targets[0, :129] = 1             # one entry into the second row tile
+    targets[1, [3, 256]] = 2         # a hole: real entries 3 and 256
+    targets[2, :] = 0                # the full width
+    np.testing.assert_array_equal(np.asarray(dlv.row_lengths(targets, n)),
+                                  [129, 257, 300, 0])
+
+
+def test_walked_tiles_counts_the_live_grid_steps():
+    """``walked_tiles`` is ceil(len / block_k) summed over the delivered
+    rows: the (spike, tile) grid steps whose tile holds a real synapse."""
+    from repro.kernels.ell_deliver import walked_tiles
+    bk, n_tiles = 128, 3
+    lens = np.array([0, 1, 127, 128, 129, 256, 384, 0], np.int32)
+    live = sum(kb * bk < ln for ln in lens for kb in range(n_tiles))
+    got = walked_tiles(jnp.asarray(lens), bk)
+    assert got.dtype == jnp.int32
+    assert int(got) == live == 0 + 1 + 1 + 1 + 2 + 2 + 3 + 0
+    assert int(walked_tiles(jnp.zeros(16, jnp.int32), bk)) == 0
+
+
 # ---------------------------------------------------------------------------
 # Full-run acceptance: scale=0.05 microcircuit, all three strategies
 # ---------------------------------------------------------------------------

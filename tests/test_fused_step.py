@@ -252,8 +252,10 @@ def test_fused_plastic_kernel_depresses_rows_sharing_a_tile():
     rows = [0, 1, 2, 9]                  # three in tile 0, one in tile 1
     zeros = jnp.zeros(n, jnp.float32)
     out = lif_deliver_plastic_pallas(
-        jnp.asarray(rows + [n, n], jnp.int32), jnp.asarray(targets),
-        jnp.asarray(weights), jnp.asarray(dbins), jnp.asarray(pmask),
+        jnp.asarray(rows + [n, n], jnp.int32),
+        jnp.asarray([k] * len(rows) + [0, 0], jnp.int32),
+        jnp.asarray(targets), jnp.asarray(weights), jnp.asarray(dbins),
+        jnp.asarray(pmask),
         jnp.zeros((d_bins, 2, n + 1)), zeros - 65.0, zeros, zeros,
         jnp.zeros(n, jnp.int32), zeros, zeros, zeros, jnp.asarray(x_post),
         zeros, jnp.asarray(3, jnp.int32), d_bins=d_bins, n_cols=n + 1, n=n,
@@ -264,6 +266,175 @@ def test_fused_plastic_kernel_depresses_rows_sharing_a_tile():
     for r in rows:
         want[r] = weights[r] - dep_coef * x_pad[targets[r]]
     np.testing.assert_array_equal(np.asarray(out[1]), want)
+
+
+# ---------------------------------------------------------------------------
+# Row tiles past a row's real length: all three ELL kernels vs XLA
+# ---------------------------------------------------------------------------
+
+#: Geometry of the walked-tile cases: N % 8 != 0, so the sentinel row N
+#: shares its 8-row table tile with real rows; K spans three row tiles.
+WN, WK, WD, WEXC, WBUDGET, BK = 45, 384, 5, 30, 12, 128
+#: Rows of chosen real length (the rest draw theirs at random).
+EMPTY_ROW, WHOLE_TILES_ROW, FULL_ROW = 3, 5, 6          # 0, 2*BK, K
+SHARED_TILE_ROWS = (16, 19)                             # one 8-row tile
+SENTINEL_TILE_ROWS = (41, 44)                           # with row N = 45
+WALK_CASES = {
+    "zero_spikes": [],
+    "budget_exact": list(range(0, 2 * WBUDGET, 2)),
+    "budget_overflow": list(range(0, 2 * WBUDGET + 6, 2)),
+    "empty_row": [EMPTY_ROW, 8, 30],
+    "whole_tiles_row": [WHOLE_TILES_ROW, 9],
+    "full_row": [FULL_ROW, 33],
+    "shared_tile": list(SHARED_TILE_ROWS),
+    "sentinel_tile": [2, *SENTINEL_TILE_ROWS],
+}
+WALK_KERNELS = ("ell_deliver", "lif_deliver_static", "lif_deliver_plastic")
+
+
+def _walk_net(seed=0):
+    """Front-packed ELL tables over three row tiles, with rows of length
+    0, exactly two tiles and the full width K, a plastic mask, and
+    random neuron state."""
+    rng = np.random.default_rng(seed)
+    n, k = WN, WK
+    lens = rng.integers(1, k, size=n)
+    lens[EMPTY_ROW], lens[WHOLE_TILES_ROW], lens[FULL_ROW] = 0, 2 * BK, k
+    pad = np.arange(k)[None, :] >= lens[:, None]
+    targets = rng.integers(0, n, size=(n, k)).astype(np.int32)
+    weights = rng.normal(scale=20.0, size=(n, k)).astype(np.float32)
+    dbins = rng.integers(1, WD, size=(n, k)).astype(np.int32)
+    pmask = (rng.random((n, k)) < 0.5).astype(np.int32)
+    targets[pad], weights[pad], dbins[pad], pmask[pad] = n, 0.0, 1, 0
+    tables = dlv.make_event_tables(jnp.asarray(targets),
+                                   jnp.asarray(weights), jnp.asarray(dbins))
+    np.testing.assert_array_equal(np.asarray(tables.row_len),
+                                  np.append(lens, 0))
+    pmask = jnp.asarray(np.vstack([pmask, np.zeros((1, k), np.int32)]))
+    _, ring, neuron, prop, ext_ex, i_dc = _synthetic_net(n, 2, WD, WEXC,
+                                                         seed=seed + 1)
+    x_pre = jnp.asarray(rng.uniform(size=n).astype(np.float32))
+    x_post = jnp.asarray(rng.uniform(size=n).astype(np.float32))
+    return tables, pmask, ring, neuron, prop, ext_ex, i_dc, x_pre, x_post
+
+
+@functools.partial(jax.jit, static_argnames=("budget", "coef", "dp", "dm"))
+def _plastic_oracle(w, tables, pmask, x_pre, x_post, spiked_prev, budget,
+                    coef, dp, dm):
+    """Depression of the delivered rows' plastic synapses as
+    ``stdp_step`` scatters it, and the trace decay + bump."""
+    n = spiked_prev.shape[0]
+    (ids,) = jnp.nonzero(spiked_prev, size=budget, fill_value=n)
+    xp = jnp.append(x_post, 0.0)
+    dw = jnp.where(pmask[ids] != 0, -(coef * xp[tables.targets[ids]]), 0.0)
+    rows = jnp.where(ids < n, ids, w.shape[0])
+    spk = spiked_prev.astype(jnp.float32)
+    return (w.at[rows].add(dw, mode="drop"), x_pre * dp + spk,
+            x_post * dm + spk)
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+@pytest.mark.parametrize("kernel", WALK_KERNELS)
+def test_ell_kernels_walk_real_tiles_bitwise(kernel, case):
+    """Each ELL kernel walks only the row tiles below the delivered rows'
+    real lengths and still equals the XLA path bit for bit: ring,
+    neuron state and spikes; for the plastic kernel also the depressed
+    weights and the traces."""
+    (tables, pmask, ring, neuron, prop, ext_ex, i_dc, x_pre,
+     x_post) = _walk_net(seed=WALK_KERNELS.index(kernel))
+    spiked_prev = np.zeros(WN, bool)
+    spiked_prev[WALK_CASES[case]] = True
+    spiked_prev = jnp.asarray(spiked_prev)
+    n_spikes = len(WALK_CASES[case])
+    t = 7
+    tt = jnp.asarray(t, jnp.int32)
+
+    if kernel == "ell_deliver":
+        got, ovf = kops.ell_deliver(ring, tables, spiked_prev, tt, WEXC,
+                                    WBUDGET, interpret=True)
+        upd, want_ovf = dlv.deliver_event(jnp.zeros_like(ring), tables,
+                                          spiked_prev, tt, WEXC, WBUDGET)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(ring + upd))
+        assert int(ovf) == int(want_ovf) == max(0, n_spikes - WBUDGET)
+        return
+
+    w_neuron, w_ring, w_spiked, w_ovf = _split_oracle(
+        neuron, ring, t, spiked_prev, tables, prop, ext_ex, i_dc, WEXC,
+        WBUDGET)
+    if kernel == "lif_deliver_static":
+        g_neuron, g_ring, g_spiked, g_ovf = kops.lif_deliver(
+            neuron, ring, tt, spiked_prev, tables, prop, ext_ex, i_dc,
+            n_exc=WEXC, spike_budget=WBUDGET, interpret=True)
+    else:
+        coef, dp, dm = 0.25, 0.9, 0.8
+        (g_neuron, g_ring, g_spiked, g_w, g_xpre, g_xpost, _,
+         g_ovf) = kops.lif_deliver_plastic(
+            neuron, ring, tt, spiked_prev, tables, tables.weights, pmask,
+            x_pre, x_post, prop, ext_ex, i_dc, n_exc=WEXC,
+            spike_budget=WBUDGET, dep_coef=coef, decay_p=dp, decay_m=dm,
+            interpret=True)
+        want = _plastic_oracle(tables.weights, tables, pmask, x_pre, x_post,
+                               spiked_prev, WBUDGET, coef, dp, dm)
+        for name, g, w in zip(("weights", "x_pre", "x_post"),
+                              (g_w, g_xpre, g_xpost), want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                          err_msg=name)
+    np.testing.assert_array_equal(np.asarray(g_ring), np.asarray(w_ring))
+    for name in NeuronState._fields:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(g_neuron, name)),
+            np.asarray(getattr(w_neuron, name)), err_msg=name)
+    np.testing.assert_array_equal(np.asarray(g_spiked),
+                                  np.asarray(w_spiked))
+    assert int(g_ovf) == int(w_ovf) == max(0, n_spikes - WBUDGET)
+
+
+@pytest.mark.parametrize("kernel", WALK_KERNELS)
+def test_ell_kernels_skip_tiles_past_len(kernel):
+    """The kernels take the row lengths they are given at their word: a
+    full-width row delivered with length ``BK`` scatters (and depresses)
+    its first row tile only."""
+    from repro.kernels.ell_deliver import ell_deliver_pallas
+    from repro.kernels.lif_deliver import (lif_deliver_pallas,
+                                           lif_deliver_plastic_pallas)
+    (tables, pmask, ring, neuron, prop, ext_ex, i_dc, x_pre,
+     x_post) = _walk_net()
+    ids = jnp.asarray([FULL_ROW, WN], jnp.int32)
+    lens = jnp.asarray([BK, 0], jnp.int32)
+    cut = tables._replace(
+        targets=tables.targets.at[FULL_ROW, BK:].set(WN),
+        weights=tables.weights.at[FULL_ROW, BK:].set(0.0),
+        dbins=tables.dbins.at[FULL_ROW, BK:].set(1))
+    t = jnp.asarray(2, jnp.int32)
+    geo = dict(d_bins=WD, n_cols=WN + 1, n_exc=WEXC, interpret=True)
+    state = (neuron.V, neuron.I_ex, neuron.I_in, neuron.refrac, ext_ex,
+             i_dc)
+    coefs = dict(dep_coef=0.25, decay_p=0.9, decay_m=0.8)
+    spk = jnp.zeros(WN, jnp.float32)
+    outs = []
+    for tb in (tables, cut):
+        if kernel == "ell_deliver":
+            outs.append(ell_deliver_pallas(ids, lens, tb.targets, tb.weights,
+                                           tb.dbins, t, **geo))
+        elif kernel == "lif_deliver_static":
+            outs.append(lif_deliver_pallas(
+                ids, lens, tb.targets, tb.weights, tb.dbins, ring, *state,
+                t, n=WN, prop=prop, **geo))
+        else:
+            out = lif_deliver_plastic_pallas(
+                ids, lens, tb.targets, tb.weights, tb.dbins, pmask, ring,
+                *state, x_pre, x_post, spk, t, n=WN, prop=prop, **coefs,
+                **geo)
+            # the untouched tail of the full row keeps its weights
+            np.testing.assert_array_equal(
+                np.asarray(out[1][FULL_ROW, BK:]),
+                np.asarray(tb.weights[FULL_ROW, BK:]))
+            outs.append(out[:1] + out[2:])
+    got, want = outs
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 # ---------------------------------------------------------------------------

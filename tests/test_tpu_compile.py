@@ -79,6 +79,7 @@ def _shapes(one_chip, n, k=K_FULL):
     rows = n + 1
     return dict(
         ids=sd((SPIKE_BUDGET,), jnp.int32),
+        lens=sd((SPIKE_BUDGET,), jnp.int32),
         targets=sd((rows, k), jnp.int32),
         weights=sd((rows, k), jnp.float32),
         dbins=sd((rows, k), jnp.int32),
@@ -102,7 +103,8 @@ def test_ell_deliver_compiles(one_chip, width):
     n = WIDTHS[width]
     s = _shapes(one_chip, n)
     _compile(ell_deliver_pallas, "ell_deliver",
-             (s["ids"], s["targets"], s["weights"], s["dbins"], s["t"]),
+             (s["ids"], s["lens"], s["targets"], s["weights"], s["dbins"],
+              s["t"]),
              d_bins=D_BINS, n_cols=n + 1, n_exc=n * 4 // 5)
 
 
@@ -111,9 +113,9 @@ def test_lif_deliver_compiles(one_chip, width):
     n = WIDTHS[width]
     s = _shapes(one_chip, n)
     _compile(lif_deliver_pallas, "lif_deliver_static",
-             (s["ids"], s["targets"], s["weights"], s["dbins"], s["ring"],
-              s["vf"], s["vf"], s["vf"], s["vi"], s["vf"], s["vf"],
-              s["t"]),
+             (s["ids"], s["lens"], s["targets"], s["weights"], s["dbins"],
+              s["ring"], s["vf"], s["vf"], s["vf"], s["vi"], s["vf"],
+              s["vf"], s["t"]),
              d_bins=D_BINS, n_cols=n + 1, n=n, n_exc=n * 4 // 5, prop=PROP)
 
 
@@ -122,9 +124,9 @@ def test_lif_deliver_plastic_compiles(one_chip, width):
     n = WIDTHS[width]
     s = _shapes(one_chip, n)
     _compile(lif_deliver_plastic_pallas, "lif_deliver_plastic",
-             (s["ids"], s["targets"], s["weights"], s["dbins"], s["pmask"],
-              s["ring"], s["vf"], s["vf"], s["vf"], s["vi"], s["vf"],
-              s["vf"], s["vf"], s["vf"], s["vf"], s["t"]),
+             (s["ids"], s["lens"], s["targets"], s["weights"], s["dbins"],
+              s["pmask"], s["ring"], s["vf"], s["vf"], s["vf"], s["vi"],
+              s["vf"], s["vf"], s["vf"], s["vf"], s["vf"], s["t"]),
              d_bins=D_BINS, n_cols=n + 1, n=n, n_exc=n * 4 // 5, prop=PROP,
              dep_coef=0.01, decay_p=0.99, decay_m=0.99)
 
