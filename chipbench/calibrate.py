@@ -4,7 +4,9 @@
     python3 chipbench/calibrate.py --workload pd14_full.scan20 \\
         --seeds 1,2,...,12 --control-seeds 1,2,3 --seconds 10 --out f.json
 
-In one process, at the cell's own size and call length: for each seed,
+In one process, at the cell's own size and call length (or, with
+``--cpu-scale s``, on the CPU at ``s`` of the neurons and the in-degree,
+with as many virtual devices as the cell has chips): for each seed,
 the program's session (built once, reset to the seed's key, past its
 presim) runs a short window like a benchmark run, and the sampled calls
 are rerun by the plain reference, giving the sound readings.  For each
@@ -12,13 +14,17 @@ control seed, the control then runs from where that window ended: the
 reference itself in the program's place, computed in bfloat16 (the
 nearest precision below the configuration's float32), its calls compared
 with the float32 reference from the same start.  The program's own
-bfloat16 state path is tried once as well and its outcome recorded.
+bfloat16 state path is tried once as well and its outcome recorded,
+except on a configuration that names a backend: the sharded backend
+keeps float32 state whatever ``state_dtype`` says.  A cell on
+several chips draws the reference's drive per chip, as its program does.
 Nothing here runs in a benchmark run.
 """
 from __future__ import annotations
 
 import argparse
 import json
+import os
 import pathlib
 import sys
 import time
@@ -30,16 +36,16 @@ from chipbench import run  # noqa: E402
 
 
 def control_readings(tb, net, cfg: dict, state, steps: int, calls: int,
-                     dtype: str = "bfloat16") -> list:
+                     dtype: str = "bfloat16", shards: int = 1) -> list:
     """``calls`` consecutive calls of the reference in ``dtype`` from the
     program's ``state``, each compared with the float32 reference from
-    the same start."""
+    the same start (both drawing the drive over ``shards`` chips)."""
     import jax.numpy as jnp
 
     from chipbench import reference
     n, k = net.targets.shape
-    c32 = reference.consts(net, cfg)
-    low = reference.consts(net, cfg, dtype)
+    c32 = reference.consts(net, cfg, shards=shards)
+    low = reference.consts(net, cfg, dtype, shards)
     before = run.export_state(state, n, k)
     out = []
     for _ in range(calls):
@@ -60,19 +66,30 @@ def main(argv=None) -> int:
     ap.add_argument("--control-seeds", default="")
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--out", required=True)
+    ap.add_argument("--cpu-scale", type=float, default=None)
     args = ap.parse_args(argv)
     cell = run.load_cell(args.workload)
-    devs = run.require_chips(cell.chips)
-    run.setup_jax()
+    cfg, mix = cell.cfg, cell.mix
+    if args.cpu_scale is None:
+        devs = run.require_chips(cell.chips)
+        run.setup_jax()
+    else:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                                   + " --xla_force_host_platform_device_"
+                                   f"count={cell.chips}")
+        import jax
+        devs = jax.devices()[:cell.chips]
+        cfg = dict(cfg, n_scaling=args.cpu_scale, k_scaling=args.cpu_scale)
     from chipbench import compare, reference
     counter = run.CompileCounter()
-    cfg, mix = cell.cfg, cell.mix
     net = run.make_network(cfg)
     seeds = [int(s) for s in args.seeds.split(",")]
     controls = {int(s) for s in args.control_seeds.split(",") if s}
-    sim, ovf = run.session(cfg, mix, net, seeds[0], counter)
+    sim, ovf = run.session(cfg, mix, net, seeds[0], counter, devices=devs)
     tb = reference.tables(net, cfg)
-    report = {"cell": cell.name, "sound": [], "control": [],
+    report = {"cell": cell.name, "scale": cfg["n_scaling"],
+              "device": devs[0].device_kind, "sound": [], "control": [],
               "program_bf16": None}
     steps = int(round(mix["chunk_ms"] / cfg["dt_ms"]))
     for i, seed in enumerate(seeds):
@@ -82,7 +99,7 @@ def main(argv=None) -> int:
         sampler = run.Sampler(int(mix["check_chunks"]), seed)
         calls, attempted, failed, span = run.window(
             sim, mix, args.seconds, sampler, counter, ovf)
-        v = run.check(sampler.kept, net, cfg, cell.limits, tb)
+        v = run.check(sampler.kept, net, cfg, cell.limits, tb, cell.chips)
         row = {"seed": seed, "calls": attempted, "failed": failed,
                "rtf": (span[1] - span[0]) / (sum(c.steps for c in calls) * cfg["dt_ms"]
                               * 1e-3) if calls else None,
@@ -92,22 +109,27 @@ def main(argv=None) -> int:
         if seed in controls:
             t = time.perf_counter()
             r = control_readings(tb, net, cfg, sim.state, steps,
-                                 int(mix["check_chunks"]))
+                                 int(mix["check_chunks"]),
+                                 shards=cell.chips)
             crow = {"seed": seed, "numbers": compare.combine(r),
                     "readings": r, "s": time.perf_counter() - t}
             report["control"].append(crow)
             print(json.dumps({"control": crow}), flush=True)
     del sim
-    try:
-        bf, ovf = run.session(cfg, mix, net, seeds[0], counter,
-                              program_dtype="bfloat16")
-        sampler = run.Sampler(int(mix["check_chunks"]), seeds[0])
-        run.window(bf, mix, args.seconds, sampler, counter, ovf)
-        v = run.check(sampler.kept, net, cfg, cell.limits, tb)
-        report["program_bf16"] = {"numbers": v["numbers"]}
-    except Exception as e:          # the program's own path may not run
-        report["program_bf16"] = {"error": repr(e)[:2000]}
-        traceback.print_exc()
+    if "backend" in cfg:
+        report["program_bf16"] = {"error": f"not tried: the {cfg['backend']}"
+                                  " backend has no bfloat16 state path"}
+    else:
+        try:
+            bf, ovf = run.session(cfg, mix, net, seeds[0], counter,
+                                  program_dtype="bfloat16", devices=devs)
+            sampler = run.Sampler(int(mix["check_chunks"]), seeds[0])
+            run.window(bf, mix, args.seconds, sampler, counter, ovf)
+            v = run.check(sampler.kept, net, cfg, cell.limits, tb)
+            report["program_bf16"] = {"numbers": v["numbers"]}
+        except Exception as e:      # the program's own path may not run
+            report["program_bf16"] = {"error": repr(e)[:2000]}
+            traceback.print_exc()
     print(json.dumps({"program_bf16": report["program_bf16"]}), flush=True)
     pathlib.Path(args.out).parent.mkdir(parents=True, exist_ok=True)
     pathlib.Path(args.out).write_text(json.dumps(report, indent=1))

@@ -17,6 +17,9 @@ from the cell's file under ``chipbench/cells/``:
     reference does reads 0.
 ``clock_gap``
     Entries of the step counter and PRNG key that differ (exact: limit 0).
+    Where the program draws its drive per chip the key is ``[chips, 2]``,
+    and every entry counts; a key of another shape differs in all of its
+    entries.
 
 A reference step with more spikes than its budget cannot vouch for the
 call; such a run is not correct either (``ref_overflow``).
@@ -35,6 +38,15 @@ def _f64(x) -> np.ndarray:
     return np.asarray(x).astype(np.float64).ravel()
 
 
+def _differ(a, b) -> float:
+    """Entries of ``a`` and ``b`` that differ (all of them where the shapes
+    do)."""
+    a, b = np.asarray(a), np.asarray(b)
+    if a.shape != b.shape:
+        return float(max(a.size, b.size))
+    return float((a != b).sum())
+
+
 def sample_gaps(before: dict, after: dict, counts, ref_after: dict,
                 ref_counts) -> Dict[str, float]:
     """The raw readings of one sampled call."""
@@ -50,9 +62,8 @@ def sample_gaps(before: dict, after: dict, counts, ref_after: dict,
         gap = np.linalg.norm(b - r)
         out[f"gap_{leaf}"] = float(gap / change) if change > 0 else (
             0.0 if gap == 0 else float("inf"))
-    out["clock"] = float(
-        (np.asarray(after["t"]) != np.asarray(ref_after["t"])).sum()
-        + (np.asarray(after["key"]) != np.asarray(ref_after["key"])).sum())
+    out["clock"] = (_differ(after["t"], ref_after["t"])
+                    + _differ(after["key"], ref_after["key"]))
     out["ref_over"] = float(np.asarray(ref_after["over"]))
     return out
 

@@ -13,11 +13,16 @@ Host spans the program opens around each call (``repro.run``, holding
 ``repro.dispatch``, ``repro.sync`` and ``repro.overflow``) share the
 trace's clock.
 
+The exchange between chips needs no map: a collective is an op of its
+own on the device, named after its opcode (``%all-reduce.5 = ...``).
+
 Against a program that records no map, or opens no ``repro.run`` span,
-every reader here returns None.
+every reader here but the exchange's returns None; against a window with
+no collective op, the exchange's does.
 """
 from __future__ import annotations
 
+import functools
 import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -25,6 +30,11 @@ from chipbench import trace as T
 
 #: The program's span around each call of its session.
 RUN_SPAN = "repro.run"
+#: Opcodes of the exchange between chips; async ones run as a ``-start``
+#: and a ``-done`` op.
+COLLECTIVES = ("all-gather", "all-reduce", "all-to-all",
+               "collective-permute", "collective-broadcast",
+               "reduce-scatter")
 
 _INSTR_RE = re.compile(r"%?([\w.-]+)")
 
@@ -62,6 +72,21 @@ def self_times(ops: Sequence[T.Event], lo: float, hi: float
     return out
 
 
+_COLLECTIVE_RE = re.compile(
+    r"[\s=](?:%s)(?:-start|-done)?\(" % "|".join(COLLECTIVES))
+
+
+@functools.lru_cache(maxsize=None)
+def is_collective(event_name: str) -> bool:
+    """Whether an op event is a collective: by the opcode in its text
+    (``... all-reduce(...)``), or by its instruction's name, which XLA
+    takes from the opcode or from JAX's name for it (``all_gather.14``)."""
+    op = instruction(event_name).split(".")[0].replace("_", "-")
+    for half in ("-start", "-done"):
+        op = op.removesuffix(half)
+    return op in COLLECTIVES or bool(_COLLECTIVE_RE.search(event_name))
+
+
 def layer_seconds(tr: T.Trace, lo: float, hi: float,
                   layers: Dict[str, str]) -> Dict[Optional[str], float]:
     """Device self seconds per layer inside ``[lo, hi]``, averaged over
@@ -90,6 +115,44 @@ def _window(run):
     if win is None or not tr.device_ops or not run.calls:
         return None
     return win
+
+
+def plane_self_times(run) -> List[List[Tuple[T.Event, float]]]:
+    """Each device plane's ops with their self times in the window (ns),
+    worked out once a run."""
+    cached = getattr(run, "_self_times", None)
+    if cached is None:
+        lo, hi = _window(run)
+        cached = run._self_times = [self_times(ops, lo, hi)
+                                    for ops in run.trace.device_ops]
+    return cached
+
+
+def op_self_seconds(run) -> Dict[str, float]:
+    """Device self seconds per op name in the window, averaged over the
+    device planes (the ``breakdown``'s ranking: a loop keeps only its own
+    time, not its body's)."""
+    if _window(run) is None:
+        return {}
+    planes = plane_self_times(run)
+    out: Dict[str, float] = {}
+    for plane in planes:
+        for e, ns in plane:
+            out[e.name] = out.get(e.name, 0.0) + ns * 1e-9 / len(planes)
+    return out
+
+
+def exchange_ms(run) -> Optional[float]:
+    """Device self time of the collective ops per simulated step (ms),
+    averaged over the device planes; None where the window holds none."""
+    if _window(run) is None:
+        return None
+    planes = plane_self_times(run)
+    found = [ns for plane in planes for e, ns in plane
+             if is_collective(e.name)]
+    if not found:
+        return None
+    return 1e-6 * sum(found) / len(planes) / sum(c.steps for c in run.calls)
 
 
 def per_step_ms(run, layer: str) -> Optional[float]:

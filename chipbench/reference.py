@@ -8,7 +8,14 @@ the program under test:
    arrivals), then clear it;
 2. the paper's Poisson drive: split the step key into the next key and one
    subkey, draw ``k_ext * rate * dt`` Poisson counts per neuron, add them
-   at the external weight;
+   at the external weight.  Where the program splits the neurons over
+   ``shards`` chips (NEST's scheme: chip ``d`` owns the contiguous slice
+   ``[d * n_loc, (d + 1) * n_loc)``, ``n_loc = ceil(N / shards)``, the
+   last slice padded with undriven neurons), the state holds one key per
+   chip, ``[shards, 2]``, and slice ``d`` draws from its own:
+   ``key_d, sub_d = split(key[d])``, then ``n_loc`` counts
+   ``poisson(sub_d, basis[slice_d])`` over the basis padded with zeros,
+   padding included; the padding's counts are dropped;
 3. exact integration of the ``iaf_psc_exp`` neuron (Rotter & Diesmann
    1999): the membrane moves with the currents of the step before, the
    currents decay and take the arrivals; a refractory neuron is held at
@@ -70,6 +77,7 @@ class Consts(NamedTuple):
     w_ext: float
     dtype: str
     stdp: Optional[Stdp]
+    shards: int = 1      # chips the drive's keys are split over
 
 
 def spike_budget(net: Network) -> int:
@@ -99,7 +107,8 @@ def stdp_consts(cfg: dict, w_ext: float) -> Optional[Stdp]:
                 w_max=p["w_max_factor"] * w_ref)
 
 
-def consts(net: Network, cfg: dict, dtype: str = "float32") -> Consts:
+def consts(net: Network, cfg: dict, dtype: str = "float32",
+           shards: int = 1) -> Consts:
     nrn, m = cfg["neuron"], net.model
     dt, tau_m, C_m = m.dt, nrn["tau_m"], nrn["C_m"]
     p22 = float(np.exp(-dt / tau_m))
@@ -116,7 +125,7 @@ def consts(net: Network, cfg: dict, dtype: str = "float32") -> Consts:
         P11_ex=float(np.exp(-dt / nrn["tau_syn_ex"])),
         P11_in=float(np.exp(-dt / nrn["tau_syn_in"])),
         ref_steps=int(round(nrn["t_ref"] / dt)), w_ext=float(m.w_ext),
-        dtype=dtype, stdp=stdp_consts(cfg, m.w_ext))
+        dtype=dtype, stdp=stdp_consts(cfg, m.w_ext), shards=int(shards))
 
 
 def tables(net: Network, cfg: dict) -> dict:
@@ -169,6 +178,19 @@ def _gather_in(order, starts, n: int, k_in: int, dump: int):
     return jnp.where(lo + col < hi, order[pos], dump)
 
 
+def _poisson(c: Consts, basis, key):
+    """Step 2's draw: the next key (or one per chip) and the counts."""
+    if c.shards == 1:
+        key, sub = jax.random.split(key, 2)
+        return key, jax.random.poisson(sub, basis, dtype=jnp.int32)
+    n_loc = -(-c.n // c.shards)
+    lam = jnp.pad(basis, (0, n_loc * c.shards - c.n)).reshape(c.shards, n_loc)
+    pairs = [jax.random.split(key[d], 2) for d in range(c.shards)]
+    ext = jnp.concatenate([jax.random.poisson(sub, lam[d], dtype=jnp.int32)
+                           for d, (_, sub) in enumerate(pairs)])
+    return jnp.stack([nxt for nxt, _ in pairs]), ext[:c.n]
+
+
 def _step(c: Consts, tb: dict, st: dict):
     dt = jnp.dtype(c.dtype)
     n = c.n
@@ -177,8 +199,7 @@ def _step(c: Consts, tb: dict, st: dict):
     arr = st["ring"][slot]
     ring = st["ring"].at[slot].set(jnp.zeros_like(arr))
     # 2. the Poisson drive
-    key, sub = jax.random.split(st["key"], 2)
-    ext = jax.random.poisson(sub, tb["basis"], dtype=jnp.int32)
+    key, ext = _poisson(c, tb["basis"], st["key"])
     in_ex = arr[0, :n] + c.w_ext * ext.astype(dt)
     in_in = arr[1, :n]
     # 3. exact integration, refractoriness, threshold

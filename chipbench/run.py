@@ -18,7 +18,9 @@ One run, in one process:
   network instance is drawn on the device from the configuration's fixed
   ``network_seed`` (:mod:`chipbench.netgen`); the program's ``Simulator``
   is built on the chip's kernels (``kernels`` from the configuration) with
-  the dynamics key from ``--seed``; the mix's one call length is compiled
+  the dynamics key from ``--seed``, on the backend the configuration names
+  (``backend``, over the cell's chips; the default one-chip backend where
+  it names none); the mix's one call length is compiled
   (JAX's persistent cache lives in ``chipbench/.cache/jax``); one call
   runs, which includes the configuration's presim;
 * the window: ``Simulator.run(chunk_ms)`` again and again until
@@ -30,9 +32,13 @@ One run, in one process:
   program's device memory is freed (:mod:`chipbench.compare`).
 
 ``--trace 1`` profiles the window and reports the per-layer metrics
-instead of the end-to-end ones.  The last line of standard output is the
-result as one JSON object; the compared numbers and their limits are the
-last lines of standard error.  Without a TPU, or with fewer chips than
+instead of the end-to-end ones.  Where the cell's file names
+``trace_calls``, the trace stops after that many calls and the window
+goes on untraced: writing and reading a trace costs host time in
+proportion to its device ops, and a cell with many ops a step on several
+chips would otherwise not end in time.  The last line of standard output
+is the result as one JSON object; the compared numbers and their limits
+are the last lines of standard error.  Without a TPU, or with fewer chips than
 the cell asks for, it exits with code 3 and prints no result.
 """
 from __future__ import annotations
@@ -109,14 +115,14 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> SimpleNamespace:
         raise SystemExit(f"no workload {name!r} in BENCHMARK.json") from None
     bench = root / "chipbench"
     applies = lambda m: name in m.get("workloads", [name])
+    own = json.loads((bench / "cells" / f"{name}.json").read_text())
     return SimpleNamespace(
         name=name, workload=wl, chips=int(wl["chips"]),
         cfg=json.loads((bench / "configs" / f"{wl['config']}.json")
                        .read_text()),
         mix=json.loads((bench / "mixes" / f"{wl['traffic']}.json")
                        .read_text()),
-        limits=json.loads((bench / "cells" / f"{name}.json")
-                          .read_text())["limits"],
+        limits=own["limits"], trace_calls=own.get("trace_calls"),
         end_to_end=[m for m in spec["end_to_end"] if applies(m)],
         per_layer=[m for m in spec["per_layer"] if applies(m)],
         bench=bench)
@@ -205,8 +211,11 @@ def connectome(net, cfg: dict):
         k_scaling=float(cfg["k_scaling"]))
 
 
-def simulator(cfg: dict, conn, seed: int, state_dtype: Optional[str] = None):
-    """The program's session, on the kernels the configuration names."""
+def simulator(cfg: dict, conn, seed: int, state_dtype: Optional[str] = None,
+              devices=None):
+    """The program's session, on the kernels the configuration names, and
+    on its ``backend`` over ``devices`` (the cell's chips) where it names
+    one."""
     import jax.numpy as jnp
 
     from repro.api import Simulator
@@ -216,15 +225,28 @@ def simulator(cfg: dict, conn, seed: int, state_dtype: Optional[str] = None):
         n_scaling=cfg["n_scaling"], k_scaling=cfg["k_scaling"],
         dt=cfg["dt_ms"], t_presim=cfg["t_presim_ms"],
         strategy=cfg["strategy"], kernels=cfg["kernels"])
-    return Simulator(
+    backend = ({} if "backend" not in cfg else
+               dict(backend=cfg["backend"], n_devices=len(devices)))
+    sim = Simulator(
         mc, connectome=conn, probes=("pop_counts",),
         plasticity=cfg.get("plasticity"),
         neuron=NeuronParams(**cfg["neuron"]), key=dynamics_key(seed),
-        state_dtype=getattr(jnp, state_dtype or cfg["state_dtype"]))
+        state_dtype=getattr(jnp, state_dtype or cfg["state_dtype"]),
+        **backend)
+    mesh = getattr(sim.backend, "mesh", None)
+    if backend and mesh is not None \
+            and set(mesh.devices.flat) != set(devices):
+        raise RuntimeError(f"the {cfg['backend']} backend's mesh holds "
+                           f"{list(mesh.devices.flat)}, not the cell's "
+                           f"chips {list(devices)}")
+    return sim
 
 
 def export_state(state, n: int, k: int) -> dict:
-    """The program's state as the reference's leaves (device arrays)."""
+    """The program's state as the reference's leaves (device arrays; host
+    arrays for the sharded backend's state)."""
+    if hasattr(state, "V"):
+        return export_sharded(state, n)
     sim, ps = (state if isinstance(state, tuple)
                and not hasattr(state, "neuron") else (state, None))
     out = dict(V=sim.neuron.V, I_ex=sim.neuron.I_ex, I_in=sim.neuron.I_in,
@@ -234,6 +256,28 @@ def export_state(state, n: int, k: int) -> dict:
         out.update(w=ps.weights[:(n + 1) * k].reshape(n + 1, k),
                    x_pre=ps.x_pre, x_post=ps.x_post)
     return out
+
+
+def export_sharded(state, n: int) -> dict:
+    """The sharded backend's state (``ShardedSimState``: neuron arrays
+    padded to ``N_pad`` = chips x ``n_loc``, the ring ``[D, 2, N_pad +
+    chips]`` as one block of ``n_loc`` columns and a dump column per chip,
+    one key per chip) as the reference's leaves: neurons cut to ``N``,
+    each block's ``n_loc`` columns side by side, cut to ``N``, and the
+    reference's zero sentinel column after them; the keys ``[chips, 2]``
+    as they are."""
+    import numpy as np
+    n_dev = state.key.shape[0]
+    n_loc = state.V.shape[0] // n_dev
+    ring = np.asarray(state.ring)
+    d = ring.shape[0]
+    cols = ring.reshape(d, 2, n_dev, n_loc + 1)[..., :n_loc]
+    ring = np.concatenate([cols.reshape(d, 2, n_dev * n_loc)[..., :n],
+                           np.zeros((d, 2, 1), ring.dtype)], axis=-1)
+    return dict(V=np.asarray(state.V)[:n], I_ex=np.asarray(state.I_ex)[:n],
+                I_in=np.asarray(state.I_in)[:n],
+                refrac=np.asarray(state.refrac)[:n], ring=ring,
+                t=np.asarray(state.t), key=np.asarray(state.key))
 
 
 # ---------------------------------------------------------------------------
@@ -280,11 +324,13 @@ def timed_call(sim, chunk_ms: float, counter: CompileCounter, ovf0: int):
 
 
 def window(sim, mix: dict, seconds: float, sampler: Sampler,
-           counter: CompileCounter, ovf: int):
+           counter: CompileCounter, ovf: int,
+           traced: Optional[int] = None):
     """Calls until ``seconds`` have passed, from ``ovf``, the overflow
-    after set-up.  Returns (calls, attempted, failed, (start, end)): the
-    window runs from the first call's start to the last call's end, and
-    everything between calls counts in it."""
+    after set-up; the profiler's trace is stopped after ``traced`` calls
+    where that is given.  Returns (calls, attempted, failed, (start,
+    end)): the window runs from the first call's start to the last call's
+    end, and everything between calls counts in it."""
     calls: List[Call] = []
     attempted = failed = 0
     w0 = time.perf_counter()
@@ -301,6 +347,9 @@ def window(sim, mix: dict, seconds: float, sampler: Sampler,
         failed += not ok
         calls.append(Call(t0, t1, counts.shape[0], counts))
         sampler.offer(before, sim.state, counts)
+        if len(calls) == traced:
+            import jax
+            jax.profiler.stop_trace()
     span = (calls[0].t0, calls[-1].t1) if calls else (w0, w0)
     return calls, attempted, failed, span
 
@@ -317,13 +366,15 @@ def rerun(tb, c, before, after, counts) -> dict:
         counts, jax.tree.map(np.asarray, ref_after), np.asarray(ref_counts))
 
 
-def check(samples, net, cfg: dict, limits: dict, tb=None) -> dict:
-    """Rerun each sampled call with the plain reference; the compared
-    numbers and the verdict."""
+def check(samples, net, cfg: dict, limits: dict, tb=None,
+          shards: int = 1) -> dict:
+    """Rerun each sampled call with the plain reference, its drive drawn
+    per chip over ``shards`` chips; the compared numbers and the
+    verdict."""
     from chipbench import compare, reference
     t0 = time.perf_counter()
     tb = reference.tables(net, cfg) if tb is None else tb
-    c = reference.consts(net, cfg)
+    c = reference.consts(net, cfg, shards=shards)
     n, k = net.targets.shape
     readings = [rerun(tb, c, export_state(a, n, k), export_state(b, n, k),
                       counts) for a, b, counts in samples]
@@ -350,11 +401,12 @@ def device_info(devs) -> dict:
 # ---------------------------------------------------------------------------
 
 def session(cfg: dict, mix: dict, net, seed: int, counter: CompileCounter,
-            program_dtype: Optional[str] = None):
-    """The program's session, compiled for the mix's call and past its
-    presim and one untimed call; returns it with its spike overflow."""
+            program_dtype: Optional[str] = None, devices=None):
+    """The program's session on ``devices``, compiled for the mix's call
+    and past its presim and one untimed call; returns it with its spike
+    overflow."""
     t = time.perf_counter()
-    sim = simulator(cfg, connectome(net, cfg), seed, program_dtype)
+    sim = simulator(cfg, connectome(net, cfg), seed, program_dtype, devices)
     log(f"setup: simulator backend={sim.backend.name} "
         f"kernels={sim.sim_config.kernels.describe()} "
         f"state_dtype={sim.sim_config.state_dtype.__name__} "
@@ -395,7 +447,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
              devices=None) -> dict:
     """Set up, measure, check; return the result object.  ``devices``
     skips the look for chips (the tests pass the CPU)."""
-    from chipbench import peaks
+    from chipbench import layers, peaks
     from chipbench import trace as T
     if devices is None:
         devs = require_chips(cell.chips)
@@ -406,24 +458,31 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     counter = CompileCounter()
     cfg, mix = cell.cfg, cell.mix
     net = make_network(cfg)
-    sim, ovf = session(cfg, mix, net, seed, counter)
+    sim, ovf = session(cfg, mix, net, seed, counter, devices=devs)
 
     sampler = Sampler(int(mix["check_chunks"]), seed)
     setup_s = process_age()
     tmp = tempfile.mkdtemp(prefix="chipbench-trace-") if trace else None
+    traced = cell.trace_calls if trace else None
     if trace:
         jax.profiler.start_trace(tmp)
     calls, attempted, failed, span = window(sim, mix, seconds, sampler,
-                                            counter, ovf)
-    if trace:
+                                            counter, ovf, traced)
+    if trace and (traced is None or len(calls) < traced):
         jax.profiler.stop_trace()
     device = device_info(devs)
     log(f"window: {len(calls)} call(s), {failed} failed, "
+        f"steps={sum(c.steps for c in calls)} "
+        f"spikes={sum(int(c.counts.sum()) for c in calls)} "
         f"memory_peak_bytes={device['memory_peak_bytes']}")
+    if traced is not None and len(calls) > traced:
+        calls = calls[:traced]           # the part the trace holds
+        span = (calls[0].t0, calls[-1].t1)
+        log(f"trace: the first {traced} call(s)")
 
     del sim
     gc.collect()
-    verdict = check(sampler.kept, net, cfg, cell.limits)
+    verdict = check(sampler.kept, net, cfg, cell.limits, shards=cell.chips)
 
     kind = devs[0].device_kind
     run = SimpleNamespace(calls=calls, span=span, net=net, cfg=cfg,
@@ -447,7 +506,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     result["metrics"] = metrics
     result["device"] = device
     if trace:
-        ops = T.op_seconds(run.trace, lo, hi)
+        ops = layers.op_self_seconds(run)
         result["breakdown"] = {
             "device_ops": [[k, v] for k, v in sorted(
                 ops.items(), key=lambda kv: -kv[1])[:10]],

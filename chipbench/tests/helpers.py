@@ -15,12 +15,13 @@ SHORT = {"chunk_ms": 1.0, "check_chunks": 8}
 
 def tiny_root(tmp: pathlib.Path, config: str = "pd14_full",
               traffic: str = "short1", limits_of: str = "pd14_full.scan20",
-              scale: float = 0.02, mix: dict = None) -> tuple:
-    """A checkout in ``tmp`` with cell ``tiny.<traffic>``: ``config`` at
-    ``scale`` of its neurons and in-degree, a 1 ms presim, under the real
-    mix ``traffic`` (or a new one written from ``mix``, by default the
-    tests' own :data:`SHORT` where no such mix exists) and the limits of
-    cell ``limits_of``.  Returns (root, cell name)."""
+              scale: float = 0.02, mix: dict = None,
+              chips: int = 1) -> tuple:
+    """A checkout in ``tmp`` with cell ``tiny.<traffic>`` on ``chips``:
+    ``config`` at ``scale`` of its neurons and in-degree, a 1 ms presim,
+    under the real mix ``traffic`` (or a new one written from ``mix``, by
+    default the tests' own :data:`SHORT` where no such mix exists) and the
+    limits of cell ``limits_of``.  Returns (root, cell name)."""
     bench = tmp / "chipbench"
     for d in ("configs", "mixes", "cells", "metrics"):
         shutil.copytree(REPO / "chipbench" / d, bench / d)
@@ -38,7 +39,8 @@ def tiny_root(tmp: pathlib.Path, config: str = "pd14_full",
                             "file": "chipbench/configs/tiny.json",
                             "reduced": [], "why": "test"})
     spec["workloads"].append({"name": name, "config": "tiny",
-                              "traffic": traffic, "chips": 1, "why": "test"})
+                              "traffic": traffic, "chips": chips,
+                              "why": "test"})
     for m in spec["end_to_end"] + spec["per_layer"]:
         if "workloads" in m:
             m["workloads"].append(name)
@@ -55,4 +57,4 @@ def run_tiny(tmp, seed: int = 2 ** 33 + 5, seconds: float = 0.3,
     root, name = tiny_root(tmp, **kw)
     cell = run.load_cell(name, root)
     return run.run_cell(cell, seed, seconds, trace,
-                        devices=jax.devices()[:1])
+                        devices=jax.devices()[:cell.chips])

@@ -54,3 +54,28 @@ def test_refuses_without_a_tpu():
          "0"], capture_output=True, text=True, env=env, timeout=120)
     assert p.returncode == 3 and p.stdout == ""
     assert "TPU" in p.stderr
+
+
+def test_trace_stops_after_the_cells_trace_calls(tmp_path, monkeypatch):
+    """A cell file's ``trace_calls`` ends the trace after that many calls;
+    the window goes on untraced and its calls are still checked."""
+    import jax
+
+    from chipbench import trace as T
+    root, name = tiny_root(tmp_path)
+    own = root / "chipbench" / "cells" / f"{name}.json"
+    own.write_text(json.dumps(dict(json.loads(own.read_text()),
+                                   trace_calls=1)))
+    stops, loaded = [], []
+    stop, load = jax.profiler.stop_trace, T.load
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: stops.append(1) or stop())
+    monkeypatch.setattr(T, "load",
+                        lambda *a: loaded.append(load(*a)) or loaded[-1])
+    cell = run.load_cell(name, root)
+    assert cell.trace_calls == 1
+    res = run.run_cell(cell, 2 ** 33 + 9, 3.0, True,
+                       devices=jax.devices()[:1])
+    assert res["correct"] is True and res["attempted"] > 1
+    assert len(stops) == 1
+    assert len(loaded[0].calls) == 1

@@ -142,3 +142,38 @@ def test_readers_return_nothing_against_a_program_without_scopes():
     for name in ("deliver_ms", "unscoped_share", "call_idle_ms"):
         assert metric_reader(REPO / "chipbench", name)(
             SimpleNamespace(trace=None, calls=[])) is None
+
+
+def test_breakdown_ranks_ops_by_self_time():
+    """A loop's body ops are not counted again in the loop: ``%while.5``
+    spans 80 ns but keeps 36 of its own; two planes average."""
+    secs = L.op_self_seconds(cell_run())
+    top = sorted(secs, key=lambda k: -secs[k])
+    assert secs[OPS[0].name] == pytest.approx(36e-9)
+    assert secs[OPS[1].name] == pytest.approx(20e-9)
+    assert [L.instruction(n) for n in top] == [
+        "while.5", "fusion.1", "sort.2", "rng.3", "copy.4"]
+    two = trace()._replace(device_ops=(OPS, OPS[1:2]))
+    assert L.op_self_seconds(cell_run(two))[OPS[1].name] == \
+        pytest.approx((20 + 10) / 2 * 1e-9)
+    assert L.op_self_seconds(SimpleNamespace(trace=None, calls=[])) == {}
+
+
+def test_exchange_reads_the_collectives_per_step():
+    """Two steps, each with a collective (its async start and done on
+    chip 0, one op on chip 1), read per step and averaged over chips."""
+    gather = (E("%all-reduce-start.5 = u32[8]{0} all-reduce-start(%p)",
+                34, 36),
+              E("%all-reduce-done.5 = u32[8]{0} all-reduce-done(%s)",
+                36, 40),
+              E("%all-gather.6 = pred[16]{0} all-gather(%q)", 72, 76))
+    chip0 = tuple(sorted(OPS + gather, key=lambda e: e.start))
+    chip1 = tuple(sorted(OPS + (E("%all-reduce.7 = u32[8]{0} "
+                                  "all-reduce(%p)", 34, 44),),
+                         key=lambda e: e.start))
+    run = cell_run(trace()._replace(device_ops=(chip0, chip1)))
+    got = metric_reader(REPO / "chipbench", "exchange_ms")(run)
+    assert got == pytest.approx(((2 + 4 + 4) + 10) / 2 / 2 * 1e-6)
+    # no collective in the window: nothing to read
+    assert metric_reader(REPO / "chipbench", "exchange_ms")(
+        cell_run()) is None
