@@ -1,8 +1,8 @@
-"""step_mfu (%): the whole step's share of the chip's peak: the least time
-the chip needs for the required work of every timed step
+"""step_mfu (%): the whole step's share of the chips' peak: the least time
+the cell's chips need for the required work of every timed step
 (:mod:`chipbench.work`, counted from the model and the spikes the calls
-returned) over the host-clock time of the whole window.  Bytes bind.  Moves
-``rtf``."""
+returned; the peak is one chip's times the chips) over the host-clock time
+of the whole window.  Bytes bind.  Moves ``rtf``."""
 import numpy as np
 
 from chipbench import work
@@ -14,5 +14,5 @@ def read(run):
     counts = np.concatenate([c.counts for c in run.calls])
     need = work.least_seconds(
         work.step_work(run.net.model, counts, bool(run.cfg.get("plasticity"))),
-        run.peaks)
+        run.peaks) / run.chips
     return 100.0 * need / (run.span[1] - run.span[0])

@@ -22,7 +22,9 @@ the program under test:
    reset, a neuron at threshold fires and resets;
 4. delivery: every synapse of every neuron that fired adds its weight to
    its target's ring slot ``(t + delay) mod D``, excitatory and inhibitory
-   sources on their own channel, sources in index order;
+   sources on their own channel, sources in index order (in reverse order
+   under ``Consts.reverse_delivery``: a sound program that sums in
+   another order, for the readings limits are set from);
 5. with pair STDP on the E->E synapses (Morrison et al. 2008): a source
    that fired depresses its plastic synapses by ``A_minus * x_post`` of
    the target, a target that fired potentiates its incoming plastic
@@ -78,6 +80,7 @@ class Consts(NamedTuple):
     dtype: str
     stdp: Optional[Stdp]
     shards: int = 1      # chips the drive's keys are split over
+    reverse_delivery: bool = False   # sum each step's arrivals backwards
 
 
 def spike_budget(net: Network) -> int:
@@ -128,44 +131,48 @@ def consts(net: Network, cfg: dict, dtype: str = "float32",
         dtype=dtype, stdp=stdp_consts(cfg, m.w_ext), shards=int(shards))
 
 
-def tables(net: Network, cfg: dict) -> dict:
-    """The network on the device, with one sentinel source row ``N`` whose
-    synapses all point at the ring's spare column ``N`` with weight 0."""
+def tables(net: Network, cfg: dict, device=None) -> dict:
+    """The network on ``device`` (the default device where none is given),
+    with one sentinel source row ``N`` whose synapses all point at the
+    ring's spare column ``N`` with weight 0.  A plastic network's weights
+    live in the state, so it has no ``weights`` table."""
     m = net.model
     n, k = net.targets.shape
     row = lambda a, fill: np.concatenate([a, np.full((1, k), fill, a.dtype)])
+    put = jnp.asarray if device is None else (
+        lambda a: jax.device_put(a, device))
     pop_of = net.pop_of
     basis = (m.k_ext[pop_of].astype(np.float32)
              * np.float32(cfg["bg_rate_hz"] * m.dt * 1e-3))
-    tb = dict(targets=jnp.asarray(row(net.targets, n)),
-              weights=jnp.asarray(row(net.weights, 0.0)),
-              dbins=jnp.asarray(row(net.dbins, 1)),
-              basis=jnp.asarray(basis),
-              i_dc=jnp.asarray(m.i_dc[pop_of].astype(np.float32)),
-              pop_of=jnp.asarray(pop_of))
+    targets = row(net.targets, n)
+    tb = dict(targets=put(targets), dbins=put(row(net.dbins, 1)),
+              basis=put(basis),
+              i_dc=put(m.i_dc[pop_of].astype(np.float32)),
+              pop_of=put(pop_of))
     if cfg.get("plasticity"):
         exc = np.arange(n + 1) < m.n_exc
-        tb["plastic"] = jnp.asarray(
-            exc[:, None] & (row(net.targets, n) < m.n_exc))
-        tb["in_syn"] = in_adjacency(tb["targets"], n)
+        tb["plastic"] = put(exc[:, None] & (targets < m.n_exc))
+        tb["in_syn"] = in_adjacency(targets, n, put)
+    else:
+        tb["weights"] = put(row(net.weights, 0.0))
     return tb
 
 
-@functools.partial(jax.jit, static_argnames=("n",))
-def _sorted_by_target(targets, n: int):
-    flat = targets.reshape(-1)
-    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-    starts = jnp.searchsorted(flat[order], jnp.arange(n + 2, dtype=flat.dtype))
-    return order, starts
-
-
-def in_adjacency(targets, n: int):
-    """``[N+1, K_in]`` flat synapse indices onto each target (row ``N`` and
-    padding: the sentinel row's first slot, which is never plastic)."""
-    order, starts = _sorted_by_target(targets, n)
-    indeg = np.diff(np.asarray(starts))[:n]
-    k_in = max(1, int(indeg.max()))
-    return _gather_in(order, starts, n=n, k_in=k_in,
+def in_adjacency(targets: np.ndarray, n: int, put):
+    """``[N+1, K_in]`` flat synapse indices onto each target, in index
+    order (row ``N`` and padding: the sentinel row's first slot, which is
+    never plastic).  The table ``[N+1, K]`` is transposed on the host, a
+    counting sort in ``O(N K)`` (scipy's CSR to CSC)."""
+    import scipy.sparse
+    m = targets.size
+    by_target = scipy.sparse.csr_matrix(
+        (np.arange(m, dtype=np.int32), targets.reshape(-1),
+         np.arange(0, m + 1, targets.shape[1])),
+        shape=(targets.shape[0], n + 2)).tocsc()
+    order = by_target.data.astype(np.int32)
+    starts = by_target.indptr[:n + 2].astype(np.int32)
+    k_in = max(1, int(np.diff(starts)[:n].max()))
+    return _gather_in(put(order), put(starts), n=n, k_in=k_in,
                       dump=n * targets.shape[1])
 
 
@@ -221,9 +228,12 @@ def _step(c: Consts, tb: dict, st: dict):
     tg = tb["targets"][ids]
     slots = (st["t"] + tb["dbins"][ids]) % c.d_bins
     ch = (ids >= c.n_exc).astype(jnp.int32)
-    lin = slots * (2 * (n + 1)) + ch[:, None] * (n + 1) + tg
-    ring = ring.reshape(-1).at[lin.reshape(-1)].add(
-        w_tab[ids].astype(dt).reshape(-1), mode="drop").reshape(ring.shape)
+    lin = (slots * (2 * (n + 1)) + ch[:, None] * (n + 1) + tg).reshape(-1)
+    arrivals = w_tab[ids].astype(dt).reshape(-1)
+    if c.reverse_delivery:
+        lin, arrivals = lin[::-1], arrivals[::-1]
+    ring = ring.reshape(-1).at[lin].add(
+        arrivals, mode="drop").reshape(ring.shape)
     out = dict(V=V1, I_ex=I_ex, I_in=I_in, refrac=refrac, ring=ring,
                t=st["t"] + 1, key=key,
                over=st["over"] + jnp.maximum(n_spk - c.budget, 0))

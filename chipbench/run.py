@@ -29,7 +29,10 @@ One run, in one process:
   that raises, compiles or drops spikes is ``failed``;
 * the check: sampled calls (drawn from the seed) are rerun by the plain
   reference from the state the program started them in, once the
-  program's device memory is freed (:mod:`chipbench.compare`).
+  program's device memory is freed (:mod:`chipbench.compare`).  The
+  reference runs on the default device (the first chip), or on the host's
+  CPU where the cell's file names ``"reference_device": "host"``: a
+  reference that does not fit one chip beside the sampled states.
 
 ``--trace 1`` profiles the window and reports the per-layer metrics
 instead of the end-to-end ones.  Where the cell's file names
@@ -123,9 +126,19 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> SimpleNamespace:
         mix=json.loads((bench / "mixes" / f"{wl['traffic']}.json")
                        .read_text()),
         limits=own["limits"], trace_calls=own.get("trace_calls"),
+        reference_device=reference_device(own.get("reference_device")),
         end_to_end=[m for m in spec["end_to_end"] if applies(m)],
         per_layer=[m for m in spec["per_layer"] if applies(m)],
         bench=bench)
+
+
+def reference_device(where: Optional[str]) -> Optional[str]:
+    """A cell file's ``reference_device``: ``None`` (the default device)
+    or ``"host"``."""
+    if where not in (None, "host"):
+        raise SystemExit(f"reference_device {where!r}: only \"host\" (the "
+                         "host's CPU) may be named; leave it out for the chip")
+    return where
 
 
 def metric_reader(bench: pathlib.Path, name: str):
@@ -242,13 +255,16 @@ def simulator(cfg: dict, conn, seed: int, state_dtype: Optional[str] = None,
     return sim
 
 
-def export_state(state, n: int, k: int) -> dict:
+def export_state(state, n: int, k: int, columns=None) -> dict:
     """The program's state as the reference's leaves (device arrays; host
-    arrays for the sharded backend's state)."""
+    arrays for the sharded backend's state).  A sharded plastic state
+    needs ``columns``, the :class:`ShardedColumns` of the network."""
     if hasattr(state, "V"):
         return export_sharded(state, n)
-    sim, ps = (state if isinstance(state, tuple)
-               and not hasattr(state, "neuron") else (state, None))
+    pair = isinstance(state, tuple) and not hasattr(state, "neuron")
+    if pair and hasattr(state[0], "V"):
+        return export_sharded_plastic(state, n, columns)
+    sim, ps = state if pair else (state, None)
     out = dict(V=sim.neuron.V, I_ex=sim.neuron.I_ex, I_in=sim.neuron.I_in,
                refrac=sim.neuron.refrac, ring=sim.ring, t=sim.t,
                key=sim.key)
@@ -278,6 +294,130 @@ def export_sharded(state, n: int) -> dict:
                 I_in=np.asarray(state.I_in)[:n],
                 refrac=np.asarray(state.refrac)[:n], ring=ring,
                 t=np.asarray(state.t), key=np.asarray(state.key))
+
+
+# The sharded plastic state.  A sharded program with plasticity hands its
+# state back as a pair ``(ShardedSimState, plastic)``:
+#
+# * ``plastic.weights``: ``[N_pad + 1, chips * k_loc]`` float32, laid out
+#   as the sharded delivery's own weight table.  Chip ``d`` (the owner of
+#   targets ``[d * n_loc, (d + 1) * n_loc)``, ``n_loc = ceil(N / chips)``)
+#   holds columns ``[d * k_loc, (d + 1) * k_loc)``: in row ``s``, source
+#   ``s``'s synapses onto ``d``'s neurons in their ELL order, then
+#   padding.  The last row is the sentinel source.
+# * ``plastic.x_pre``, ``plastic.x_post``: ``[N_pad]`` float32 by global
+#   neuron id, the padding neurons after ``N``.
+#
+# It is the layout the sharded delivery already reads, so a program keeps
+# its live weights there and needs no per-step view of another layout.
+
+class ShardedColumns:
+    """Where the sharded plastic layout keeps each synapse of the
+    network's ELL table ``net.targets``, worked out from the owner rule
+    alone (chip ``target // n_loc``): entry ``(s, j)`` onto chip ``d`` is
+    the ``r``-th of row ``s``'s synapses onto ``d`` and sits in column
+    ``d * k_loc + r``.  The map is built once a run, for the ``k_loc`` of
+    the first state read, ``ROWS`` rows and one chip at a time, so that
+    host memory stays bounded."""
+
+    ROWS = 4096
+
+    def __init__(self, net, chips: int):
+        self.net, self.chips = net, chips
+        self.n_loc = -(-net.targets.shape[0] // chips)
+        self.k_loc = self.flat = None
+
+    def _owners(self):
+        """Each block of rows ``r0:r1`` with the owning chip of each
+        entry (``chips`` in the padding)."""
+        import numpy as np
+        targets = self.net.targets
+        n = targets.shape[0]
+        for r0 in range(0, n, self.ROWS):
+            t = targets[r0:r0 + self.ROWS]
+            yield r0, r0 + t.shape[0], np.where(t < n, t // self.n_loc,
+                                                self.chips)
+
+    def least_k_loc(self) -> int:
+        """The fewest columns a chip's block needs: its most synapses from
+        one source."""
+        import numpy as np
+        return max(1, max(int(np.count_nonzero(owner == d, axis=1).max())
+                          for _, _, owner in self._owners()
+                          for d in range(self.chips)))
+
+    def index(self, k_loc: int):
+        """``[N, K]`` int32: each synapse's flat index in the ``[N_pad +
+        1, chips * k_loc]`` sharded weights, -1 in the padding."""
+        import numpy as np
+        if self.k_loc == k_loc:
+            return self.flat
+        width = self.chips * k_loc
+        if (self.n_loc * self.chips + 1) * width >= 2 ** 31:
+            raise ValueError(f"{width} columns overflow an int32 index")
+        flat = np.full(self.net.targets.shape, -1, np.int32)
+        for r0, r1, owner in self._owners():
+            base = np.arange(r0, r1, dtype=np.int32)[:, None] * width
+            for d in range(self.chips):
+                mine = owner == d
+                rank = np.cumsum(mine, axis=1, dtype=np.int32) - 1
+                if rank[:, -1].max() >= k_loc:
+                    raise ValueError(f"a source has more than {k_loc} "
+                                     f"synapses onto chip {d}")
+                flat[r0:r1][mine] = (base + d * k_loc + rank)[mine]
+        self.k_loc, self.flat = k_loc, flat
+        return flat
+
+    def to_reference(self, weights):
+        """``[N_pad + 1, chips * k_loc]`` sharded weights as the
+        reference's ``[N + 1, K]``: ``net.targets``' column order, the
+        network's own values in its padding, the sentinel row 0."""
+        import numpy as np
+        weights = np.asarray(weights)
+        k_loc = weights.shape[1] // self.chips
+        if weights.shape != (self.n_loc * self.chips + 1,
+                             self.chips * k_loc):
+            raise ValueError(f"sharded weights {weights.shape} do not fit "
+                             f"{self.chips} chips of {self.n_loc} neurons")
+        flat, src = self.index(k_loc), weights.reshape(-1)
+        n, k = flat.shape
+        w = np.zeros((n + 1, k), np.float32)
+        for r0 in range(0, n, self.ROWS):
+            at = flat[r0:r0 + self.ROWS]
+            w[r0:r0 + at.shape[0]] = np.where(
+                at >= 0, src.take(np.maximum(at, 0)),
+                self.net.weights[r0:r0 + self.ROWS])
+        return w
+
+    def to_sharded(self, w, k_loc: int):
+        """The reference's ``[N + 1, K]`` weights in the sharded layout
+        (the inverse of :meth:`to_reference`; padding 0)."""
+        import numpy as np
+        w = np.asarray(w)
+        flat = self.index(k_loc)
+        out = np.zeros((self.n_loc * self.chips + 1) * self.chips * k_loc,
+                       np.float32)
+        for r0 in range(0, flat.shape[0], self.ROWS):
+            at = flat[r0:r0 + self.ROWS]
+            real = at >= 0
+            out[at[real]] = w[r0:r0 + at.shape[0]][real]
+        return out.reshape(self.n_loc * self.chips + 1, -1)
+
+
+def export_sharded_plastic(state, n: int, columns: ShardedColumns) -> dict:
+    """A sharded plastic state (the pair above) as the reference's leaves:
+    :func:`export_sharded`'s, the weights in ``net.targets``' order
+    (:meth:`ShardedColumns.to_reference`) and both traces cut to ``N``."""
+    import numpy as np
+    if columns is None:
+        raise ValueError("a sharded plastic state needs the network's "
+                         "ShardedColumns to be read")
+    sim, plastic = state
+    out = export_sharded(sim, n)
+    out.update(w=columns.to_reference(plastic.weights),
+               x_pre=np.asarray(plastic.x_pre)[:n],
+               x_post=np.asarray(plastic.x_post)[:n])
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -366,21 +506,48 @@ def rerun(tb, c, before, after, counts) -> dict:
         counts, jax.tree.map(np.asarray, ref_after), np.asarray(ref_counts))
 
 
+def host_cpu():
+    """The host's CPU device, for a reference that does not fit a chip;
+    never a chip in its place."""
+    import jax
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError as e:
+        raise RuntimeError("reference_device \"host\": JAX has no CPU "
+                           f"backend here ({e}); the reference will not "
+                           "run on a chip in its place") from e
+
+
 def check(samples, net, cfg: dict, limits: dict, tb=None,
-          shards: int = 1) -> dict:
+          shards: int = 1, where: Optional[str] = None) -> dict:
     """Rerun each sampled call with the plain reference, its drive drawn
-    per chip over ``shards`` chips; the compared numbers and the
-    verdict."""
+    per chip over ``shards`` chips, on the default device or, where
+    ``where`` is ``"host"``, on the host's CPU; the compared numbers and
+    the verdict."""
+    import jax
+
     from chipbench import compare, reference
     t0 = time.perf_counter()
-    tb = reference.tables(net, cfg) if tb is None else tb
-    c = reference.consts(net, cfg, shards=shards)
+    device = host_cpu() if where == "host" else None
+    place = (contextlib.nullcontext() if device is None
+             else jax.default_device(device))
     n, k = net.targets.shape
-    readings = [rerun(tb, c, export_state(a, n, k), export_state(b, n, k),
-                      counts) for a, b, counts in samples]
+    columns = ShardedColumns(net, shards)       # built at its first use
+    with place:
+        tb = reference.tables(net, cfg, device) if tb is None else tb
+        c = reference.consts(net, cfg, shards=shards)
+        readings = []
+        for a, b, counts in samples:
+            before = export_state(a, n, k, columns)
+            if device is not None:
+                before = jax.device_put(before, device)
+            readings.append(rerun(tb, c, before,
+                                  export_state(b, n, k, columns), counts))
     numbers = compare.combine(readings)
     log(f"check: {len(readings)} call(s) rerun by the reference in "
-        f"{time.perf_counter() - t0:.3f} s; per call: "
+        f"{time.perf_counter() - t0:.3f} s"
+        f"{' on the host' if device is not None else ''}; host_rss_peak_gib="
+        f"{host_rss_gib():.3f}; per call: "
         + "; ".join(" ".join(f"{k}={v:.6g}" for k, v in r.items())
                     for r in readings))
     return {"numbers": numbers, "readings": readings,
@@ -482,11 +649,13 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
 
     del sim
     gc.collect()
-    verdict = check(sampler.kept, net, cfg, cell.limits, shards=cell.chips)
+    verdict = check(sampler.kept, net, cfg, cell.limits, shards=cell.chips,
+                    where=cell.reference_device)
 
     kind = devs[0].device_kind
     run = SimpleNamespace(calls=calls, span=span, net=net, cfg=cfg,
                           mix=mix, setup_s=setup_s, trace=None,
+                          chips=len(devs),
                           peaks=(peaks.peaks_for(kind) if kind in peaks.PEAKS
                                  else None))
     wanted = cell.per_layer if trace else cell.end_to_end

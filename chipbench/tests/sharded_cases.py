@@ -3,17 +3,22 @@ own with four virtual CPU devices (a process fixes its device count when
 JAX starts): a tiny ``pd14_full_x4`` cell through ``run.run_cell``, sound
 and with the timed path broken underneath, the control, the one-key
 reference against the per-chip drive, the mesh check, and the program's
-collectives.  Prints one JSON object.
+collectives; and a tiny ``pd14_full_stdp_x4`` cell, whose program is a
+stand-in (the reference in its place, its state handed back in the
+sharded plastic layout), sound and with that state broken, its reference
+on the host.  Prints one JSON object.
 
     python3 chipbench/tests/sharded_cases.py <scratch directory>
 """
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import json
 import os
 import pathlib
 import sys
+from types import SimpleNamespace
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
@@ -22,10 +27,12 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[2]))
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
 
 from chipbench import calibrate, compare, layers, reference, run  # noqa: E402
 from chipbench.tests.helpers import run_tiny, tiny_root  # noqa: E402
 from repro.api import backends  # noqa: E402
+from repro.core.distributed import localize_ell  # noqa: E402
 
 CHIPS = 4
 SEED = 2 ** 33 + 17
@@ -172,6 +179,141 @@ def mesh_elsewhere(tmp: pathlib.Path) -> str:
     return ""
 
 
+class StandIn:
+    """A sound sharded plastic program: the plain reference with the drive
+    drawn per chip in the program's place.  It keeps its state as the
+    reference's leaves and hands it back as ``run.py``'s layout contract
+    says, the weights laid out by the program's own ``localize_ell``;
+    ``fault(state, start)`` breaks what it hands back."""
+
+    def __init__(self, net, cfg: dict, conn, seed: int, fault=None):
+        m = net.model
+        n, k = net.targets.shape
+        self.conn, self.fault, self.dt = conn, fault, cfg["dt_ms"]
+        self.tb = reference.tables(net, cfg)
+        self.c = reference.consts(net, cfg, shards=CHIPS)
+        key = run.dynamics_key(seed)
+        rng = np.random.default_rng(seed % 2 ** 32)
+        nrn = cfg["neuron"]
+        self.ref = dict(   # V spread up to threshold: plasticity from the start
+            V=rng.uniform(nrn["E_L"], nrn["V_th"], n).astype(np.float32),
+            I_ex=np.zeros(n, np.float32), I_in=np.zeros(n, np.float32),
+            refrac=np.zeros(n, np.int32),
+            ring=np.zeros((m.d_max_bins, 2, n + 1), np.float32),
+            t=np.int32(0),
+            key=np.stack([np.asarray(jax.random.fold_in(key, d))
+                          for d in range(CHIPS)]),
+            w=np.concatenate([net.weights, np.zeros((1, k), np.float32)]),
+            x_pre=np.zeros(n, np.float32), x_post=np.zeros(n, np.float32))
+        self.over = 0
+        self.start = self.state = self.lay_out()
+        self.backend = SimpleNamespace(name="stand-in", caches=lambda: [],
+                                       overflow=lambda state: self.over)
+        self.sim_config = SimpleNamespace(
+            kernels=SimpleNamespace(describe=lambda: "reference"),
+            state_dtype=jnp.float32, spike_budget=self.c.budget)
+
+    def lay_out(self):
+        n = self.conn.n_total
+        tables, meta = localize_ell(dataclasses.replace(
+            self.conn, weights=np.asarray(self.ref["w"])[:n]), CHIPS)
+        return calibrate.laid_out(self.ref, meta["n_loc"], tables.weights)
+
+    def warmup(self, t_ms: float) -> None:
+        pass
+
+    def run(self, t_ms: float):
+        after, counts = reference.chunk(self.tb, self.ref,
+                                        int(round(t_ms / self.dt)), self.c)
+        self.over += int(after["over"])
+        self.ref = {name: after[name] for name in self.ref}
+        self.state = self.lay_out()
+        if self.fault is not None:
+            self.state = self.fault(self.state, self.start)
+        return SimpleNamespace(data={"pop_counts": counts},
+                               overflow=self.over)
+
+
+def _weights_unchanged(state, start):
+    return state[0], start[1]
+
+
+def _traces_swapped(state, start):
+    sim, plastic = state
+    return sim, SimpleNamespace(weights=plastic.weights,
+                                x_pre=plastic.x_post, x_post=plastic.x_pre)
+
+
+def _flat_export(state, n, columns):
+    """Sharded weights read as the one-chip export reads its flat ones."""
+    out = run.export_sharded(state[0], n)
+    k = columns.net.targets.shape[1]
+    out.update(w=np.asarray(state[1].weights).reshape(-1)[:(n + 1) * k]
+               .reshape(n + 1, k),
+               x_pre=np.asarray(state[1].x_pre)[:n],
+               x_post=np.asarray(state[1].x_post)[:n])
+    return out
+
+
+#: Each fault: what breaks the state handed back, what breaks its export,
+#: and the pair-STDP constants that change from the configuration's.  The
+#: configuration's two traces decay alike (``tau_plus`` = ``tau_minus``),
+#: so there ``x_pre`` and ``x_post`` are one vector and their swap changes
+#: nothing; the swap is planted where they decay ten times apart (a trace
+#: is compared against its own change over a call, which the swap of two
+#: traces that decay alike within a factor of five stays under).
+PLASTIC_FAULTS = {
+    "blocks_swapped": (lambda state, start:
+                       calibrate.swap_blocks(state, 0, 1), None, {}),
+    "weights_unchanged": (_weights_unchanged, None, {}),
+    "traces_swapped": (_traces_swapped, None, {"tau_minus": 2.0}),
+    "flat_export": (None, _flat_export, {}),
+}
+
+
+def plastic(tmp: pathlib.Path, fault=None, export=None,
+            stdp: dict = None) -> dict:
+    """The stand-in's cell through ``run.run_cell``, the default device
+    the last of the four, its reference on the host (``reference_device``
+    ``"host"``), the pair-STDP constants ``stdp`` changed; with the
+    devices of the tables the check built."""
+    root, name = tiny_root(tmp, config="pd14_full_stdp_x4", traffic="scan5",
+                           limits_of="pd14_full_x4.scan20", chips=CHIPS)
+    tiny = root / "chipbench" / "configs" / "tiny.json"
+    cfg = json.loads(tiny.read_text())
+    tiny.write_text(json.dumps(dict(cfg, plasticity=dict(
+        cfg["plasticity"], **(stdp or {})))))
+    own = root / "chipbench" / "cells" / f"{name}.json"
+    own.write_text(json.dumps(dict(json.loads(own.read_text()),
+                                   reference_device="host")))
+    cell = run.load_cell(name, root)
+    nets, built = [], []
+    make, tables = run.make_network, reference.tables
+
+    def simulator(cfg, conn, seed, state_dtype=None, devices=None):
+        return StandIn(nets[-1], cfg, conn, seed, fault)
+
+    def record(net, cfg, device=None):
+        tb = tables(net, cfg, device)
+        built.append({k: [[str(d) for d in v.devices()], v.committed]
+                      for k, v in tb.items()})
+        return tb
+    with contextlib.ExitStack() as stack:
+        stack.enter_context(jax.default_device(jax.devices()[CHIPS - 1]))
+        stack.enter_context(patched(
+            run, "make_network", lambda cfg: nets.append(make(cfg))
+            or nets[-1]))
+        stack.enter_context(patched(run, "simulator", simulator))
+        stack.enter_context(patched(reference, "tables", record))
+        if export is not None:
+            stack.enter_context(patched(run, "export_sharded_plastic",
+                                        export))
+        res = run.run_cell(cell, SEED, 2.0, False,
+                           devices=jax.devices()[:CHIPS])
+    return dict(checks(res), check_tables=built[-1],
+                host=str(jax.devices("cpu")[0]))
+
+
 def main(scratch: str) -> None:
     base = pathlib.Path(scratch)
     # the sound window is long enough for two calls on a loaded CPU
@@ -183,6 +325,10 @@ def main(scratch: str) -> None:
     for fault, (obj, attr, value) in FAULTS.items():
         with patched(obj, attr, value):
             out[fault] = checks(run_tiny(base / fault, SEED, **KW))
+    out["plastic"] = {"sound": plastic(base / "plastic")}
+    for fault, (state, export, stdp) in PLASTIC_FAULTS.items():
+        out["plastic"][fault] = plastic(base / f"plastic_{fault}", state,
+                                        export, stdp)
     print(json.dumps(out), flush=True)
 
 

@@ -1,9 +1,12 @@
-"""The four-chip cell's harness on four virtual CPU devices: a tiny
+"""The four-chip cells' harness on four virtual CPU devices: a tiny
 ``pd14_full_x4`` network (2 % of the neurons and the in-degree) through
 ``run.run_cell`` and the sharded backend, checked against the reference
-that draws the drive per chip.  The cases run once, in a process of
-their own (``sharded_cases.py``), as ``tests/test_distributed.py`` runs
-its multi-device cases; the state mapping needs no device."""
+that draws the drive per chip; and a tiny ``pd14_full_stdp_x4`` network
+whose program is the reference itself, handing its state back in the
+sharded plastic layout, checked on the host.  The cases run once, in a
+process of their own (``sharded_cases.py``), as
+``tests/test_distributed.py`` runs its multi-device cases; the state
+mappings need no device."""
 import json
 import os
 import subprocess
@@ -12,8 +15,8 @@ import sys
 import numpy as np
 import pytest
 
-from chipbench import compare, layers, run
-from repro.core.distributed import ShardedSimState
+from chipbench import compare, layers, netgen, run
+from repro.core.distributed import ShardedSimState, localize_ell
 
 from .helpers import REPO
 
@@ -71,6 +74,59 @@ def test_exchange_reader_names_the_programs_collectives(cases):
 
 def test_mesh_on_other_chips_is_refused(cases):
     assert "not the cell's chips" in cases["mesh_elsewhere"]
+
+
+def test_sound_sharded_plastic_program_is_correct(cases):
+    """The reference in the program's place, its state handed back in the
+    sharded plastic layout, reads as itself."""
+    r = cases["plastic"]["sound"]
+    assert r["correct"] is True, r
+    assert r["failed"] == 0 and r["attempted"] > 1
+    assert r["counts_gap"] == 0 and r["clock_gap"] == 0
+    assert r["state_gap"] == 0 and r["ref_overflow"] == 0
+
+
+@pytest.mark.parametrize("fault", ["blocks_swapped", "weights_unchanged",
+                                   "traces_swapped", "flat_export"])
+def test_sharded_plastic_fault_is_not_correct(cases, fault):
+    r = cases["plastic"][fault]
+    assert r["correct"] is False, r
+
+
+def test_host_reference_tables_are_on_the_host(cases):
+    """``reference_device: "host"`` commits the check's tables to the
+    CPU device, whatever the default device is (here the fourth)."""
+    r = cases["plastic"]["sound"]
+    assert r["check_tables"], r
+    for leaf, (devices, committed) in r["check_tables"].items():
+        assert devices == [r["host"]] and committed is True, leaf
+
+
+def test_reference_device_names_only_the_host():
+    assert run.reference_device(None) is None
+    assert run.reference_device("host") == "host"
+    with pytest.raises(SystemExit):
+        run.reference_device("chip")
+
+
+@pytest.mark.parametrize("chips", [2, 4])
+def test_sharded_columns_invert_the_programs_layout(chips):
+    """The program's own layout of the weights (``localize_ell``), read
+    back by the harness's map, is ``net.weights`` and the sentinel row,
+    exactly; the map's inverse is the program's layout."""
+    cfg = json.loads((REPO / "chipbench/configs/pd14_full.json")
+                     .read_text())
+    cfg.update(n_scaling=0.02, k_scaling=0.02)
+    net = netgen.network(cfg)
+    tables, meta = localize_ell(run.connectome(net, cfg), chips)
+    cols = run.ShardedColumns(net, chips)
+    assert cols.least_k_loc() == meta["k_loc"]
+    n, k = net.targets.shape
+    w = cols.to_reference(tables.weights)
+    np.testing.assert_array_equal(w[:n], net.weights)
+    assert w.shape == (n + 1, k) and not w[n].any()
+    np.testing.assert_array_equal(cols.to_sharded(w, meta["k_loc"]),
+                                  tables.weights)
 
 
 def test_export_maps_sharded_state_to_reference_leaves():

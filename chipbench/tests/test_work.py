@@ -55,7 +55,9 @@ def test_step_work_by_hand(net):
                                      + 8 * pot)
 
 
-def test_step_mfu_is_least_time_over_call_time(net):
+@pytest.mark.parametrize("chips", [1, 4])
+def test_step_mfu_is_least_time_over_call_time(net, chips):
+    """The peak of ``chips`` chips is one chip's times the chips."""
     cfg, nw = net
     m = nw.model
     counts = np.ones((10, len(m.pops)), np.int32)
@@ -63,12 +65,13 @@ def test_step_mfu_is_least_time_over_call_time(net):
     # two calls with host time between them: the whole window counts
     calls = [Call(0.0, 0.5, 5, counts[:5]), Call(0.6, 1.0, 5, counts[5:])]
     run = SimpleNamespace(calls=calls, span=(0.0, 1.0), net=nw,
-                          cfg=cfg, peaks=peaks)
+                          cfg=cfg, peaks=peaks, chips=chips)
     got = metric_reader(REPO / "chipbench", "step_mfu")(run)
-    need = work.step_work(m, counts, False).bytes / peaks.hbm_bw
+    need = work.step_work(m, counts, False).bytes / (chips * peaks.hbm_bw)
     assert got == pytest.approx(100 * need / 1.0)
     assert metric_reader(REPO / "chipbench", "step_mfu")(
-        SimpleNamespace(calls=[], net=nw, cfg=cfg, peaks=peaks)) is None
+        SimpleNamespace(calls=[], net=nw, cfg=cfg, peaks=peaks,
+                        chips=chips)) is None
 
 
 def test_rtf_counts_host_time_between_calls(net):
